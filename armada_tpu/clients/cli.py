@@ -314,9 +314,9 @@ def cmd_policy(args):
     if args.policy_cmd == "ab":
         # Local replay, no server needed: the same harness as
         # tools/policy_ab.py.
-        from ..utils.platform import ensure_healthy_backend
+        from ..utils.platform import setup_jax
 
-        ensure_healthy_backend()
+        setup_jax()
 
         from ..trace.policy_ab import (
             DEFAULT_CANDIDATES,
@@ -492,6 +492,9 @@ def _render_drain_status(status: dict) -> str:
 def cmd_server(args):
     from ..core.config import SchedulingConfig
     from ..services.server import ControlPlane
+    from ..utils.platform import setup_jax
+
+    setup_jax()
 
     config = SchedulingConfig()
     if args.config:

@@ -24,21 +24,35 @@ from fractions import Fraction
 
 import numpy as np
 
-try:  # C++ fast path (native/quantity.cpp); exact-Fraction fallback below.
-    import _armada_native as _native
-except ImportError:  # pragma: no cover
-    _native = None
+# C++ fast path (native/quantity.cpp), loaded at first use so that
+# ensure_native can replace a stale build first; exact-Fraction fallback.
+_native = None
+_native_loaded = False
+
+
+def _native_module():
+    global _native, _native_loaded
+    if not _native_loaded:
+        _native_loaded = True
+        try:
+            import _armada_native as mod
+
+            _native = mod
+        except ImportError:  # pragma: no cover
+            pass
+    return _native
 
 
 def ensure_native(timeout: float = 180.0) -> bool:
-    """Build the C++ quantity parser (native/) if it isn't importable yet
-    and load it; returns availability. The .so is a build artifact (not
-    committed), so fresh checkouts compile it on first demand — callers on
-    hot startup paths (bench, server boot) invoke this once up front."""
-    global _native
-    if _native is not None:
-        return True
+    """Build the C++ quantity parser from native/quantity.cpp, put it
+    next to armada_tpu/ and load it; returns availability. The .so is a
+    build artifact (not committed): the fresh build always replaces any
+    copy already there, which may be stale. Callers on hot startup paths
+    (bench, chip_smoke) invoke this once up front, before the first
+    quantity is encoded."""
+    global _native, _native_loaded
     import importlib
+    import os
     import pathlib
     import subprocess
     import sys
@@ -57,11 +71,13 @@ def ensure_native(timeout: float = 180.0) -> bool:
         )
         for so in ndir.glob("_armada_native*.so"):
             dest = root / so.name
-            if not dest.exists():
-                dest.write_bytes(so.read_bytes())
+            tmp = dest.with_name(dest.name + ".tmp")
+            tmp.write_bytes(so.read_bytes())
+            os.replace(tmp, dest)
         if str(root) not in sys.path:
             sys.path.insert(0, str(root))
         _native = importlib.import_module("_armada_native")
+        _native_loaded = True
         return True
     except Exception:  # pragma: no cover
         return False
@@ -278,9 +294,10 @@ class ResourceListFactory:
 
     def _encode_unique(self, requests: list, *, ceil: bool) -> np.ndarray:
         U = len(requests)
-        if _native is not None and U:
+        native = _native_module()
+        if native is not None and U:
             try:
-                raw = _native.encode_requests(
+                raw = native.encode_requests(
                     list(requests), list(self.names), list(self.scales), ceil
                 )
                 return (

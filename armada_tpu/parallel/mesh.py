@@ -64,22 +64,6 @@ def node_specs(axis) -> dict:
 _NODE_SHARDED = node_specs("nodes")
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """jax.shard_map across jax versions: the promoted jax.shard_map
-    spells the replication check `check_vma`; the older
-    jax.experimental.shard_map spells it `check_rep`."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-    )
-
-
 def make_node_mesh(devices=None) -> Mesh:
     devices = devices if devices is not None else jax.devices()
     return Mesh(np.asarray(devices), ("nodes",))
@@ -186,8 +170,9 @@ def sharded_solve(mesh: Mesh, dist, specs: dict):
 
     def build(dev: DeviceRound):
         return jax.jit(
-            shard_map_compat(
-                inner, mesh, in_specs=(spec_tree(dev, specs),), out_specs=P()
+            jax.shard_map(
+                inner, mesh=mesh, in_specs=(spec_tree(dev, specs),),
+                out_specs=P(), check_vma=False,
             )
         )
 

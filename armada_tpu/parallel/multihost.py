@@ -71,8 +71,9 @@ def hierarchical_sharded_solve(mesh: Mesh, kernel_path: str = "lax"):
 
     kernel_path "pallas"/"native" swaps in PallasHierarchicalDist
     (solver/dist_pallas.py): the host-level winner exchange runs as the
-    pallas tree/ring kernel — bit-exact by construction, so the runner
-    stays interchangeable with the lax one rung-for-rung."""
+    pallas kernel ("native": the ICI ring kernel) — bit-exact by
+    construction, so the runner stays interchangeable with the lax one
+    rung-for-rung."""
     if mesh.devices.ndim != 2 or mesh.axis_names != (HOST_AXIS, CHIP_AXIS):
         raise ValueError(
             f"expected a ({HOST_AXIS}, {CHIP_AXIS}) mesh, got "
@@ -80,12 +81,16 @@ def hierarchical_sharded_solve(mesh: Mesh, kernel_path: str = "lax"):
         )
     n_hosts, n_chips = mesh.devices.shape
     if kernel_path in ("pallas", "native"):
-        from ..solver.dist_pallas import PallasHierarchicalDist as _Dist
+        from ..solver.dist_pallas import PallasHierarchicalDist
+
+        dist = PallasHierarchicalDist(
+            HOST_AXIS, CHIP_AXIS, n_hosts, n_chips, stats=CollectiveStats(),
+            ring=kernel_path == "native",
+        )
     else:
-        _Dist = HierarchicalDist
-    dist = _Dist(
-        HOST_AXIS, CHIP_AXIS, n_hosts, n_chips, stats=CollectiveStats()
-    )
+        dist = HierarchicalDist(
+            HOST_AXIS, CHIP_AXIS, n_hosts, n_chips, stats=CollectiveStats()
+        )
     return sharded_solve(mesh, dist, _NODE_SHARDED_2D)
 
 

@@ -102,6 +102,9 @@ def main(argv=None):
     p.add_argument("--json", action="store_true", help="machine-readable output")
     args = p.parse_args(argv)
 
+    from ..utils.platform import setup_jax
+
+    setup_jax()
     config = SchedulingConfig()
     if args.config:
         with open(args.config) as f:

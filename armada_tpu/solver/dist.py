@@ -31,7 +31,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-from ..ops.select import lex_argmin, masked_keys
+from ..ops.select import lex_argmin, lexsort, masked_keys
 
 
 @dataclasses.dataclass
@@ -116,9 +116,7 @@ def _fill_sort(keys, mask, B, path="lax", nbits=None):
 
         return fill_sort_path(keys, mask, B, path, nbits)
     mk = masked_keys(keys, mask)
-    # jnp.lexsort: LAST key is primary -> reverse (ours is first-primary).
-    order = jnp.lexsort(tuple(reversed(mk)))
-    return order[:B], mk
+    return lexsort(mk)[:B], mk
 
 
 class LocalDist:
@@ -281,7 +279,7 @@ class ShardDist:
         ]
         gcaps = jax.lax.all_gather(lcaps, self.axis).reshape(-1)
         ggids = jax.lax.all_gather(lgids, self.axis).reshape(-1)
-        order = jnp.lexsort(tuple(reversed(gkeys)))[:B]
+        order = lexsort(gkeys)[:B]
         return gcaps[order], ggids[order]
 
 
@@ -400,12 +398,12 @@ class HierarchicalDist(ShardDist):
         ]
         ccaps = jax.lax.all_gather(lcaps, self.chip_axis).reshape(-1)
         cgids = jax.lax.all_gather(lgids, self.chip_axis).reshape(-1)
-        horder = jnp.lexsort(tuple(reversed(ckeys)))[:B]
+        horder = lexsort(ckeys)[:B]
         hkeys = [k[horder] for k in ckeys]
         gkeys = [
             jax.lax.all_gather(k, self.host_axis).reshape(-1) for k in hkeys
         ]
         gcaps = jax.lax.all_gather(ccaps[horder], self.host_axis).reshape(-1)
         ggids = jax.lax.all_gather(cgids[horder], self.host_axis).reshape(-1)
-        order = jnp.lexsort(tuple(reversed(gkeys)))[:B]
+        order = lexsort(gkeys)[:B]
         return gcaps[order], ggids[order]

@@ -5,14 +5,12 @@
 the gathered [hosts, keys] block. This subclass keeps the chip-level ICI
 stage verbatim — the per-host winner is still produced by gather+argmin
 inside a host, where XLA already fuses it — and replaces the host-level
-finish with `ops/pallas_kernels.winner_reduce`: a pallas tree-reduction
-over the gathered tuples that runs interpreted (bit-exact, CPU tier-1)
-everywhere a TPU isn't attached, and compiles natively behind the
-`native_available()` probe. On hardware the same tuple exchange can run
-as an ICI ring of `make_async_remote_copy` steps
-(`pallas_kernels.ring_winner_exchange`), overlapping each DMA hop with
-the comparison of the previous arrival; the tree kernel is its bit-exact
-stand-in everywhere else, and `CollectiveStats.ring_steps`/`ring_bytes`
+finish with `ops/pallas_kernels.winner_reduce`: a pallas reduction over
+the gathered tuples that runs interpreted (bit-exact, CPU tier-1)
+everywhere a TPU isn't attached, and compiles natively on one. On the
+`native` path (TPU only) the host-level exchange is instead an ICI ring
+of `make_async_remote_copy` steps (`pallas_kernels.ring_winner_exchange`)
+with no all_gather at all; `CollectiveStats.ring_steps`/`ring_bytes`
 book the exchange's fabric cost either way.
 
 Selection semantics are unchanged by construction: the reduction's last
@@ -34,7 +32,11 @@ from .dist import HierarchicalDist
 
 class PallasHierarchicalDist(HierarchicalDist):
     """HierarchicalDist with the host-level winner exchange reduced by
-    the pallas tree kernel (ring on native TPU)."""
+    the pallas kernel, or by the ICI ring kernel when `ring`."""
+
+    def __init__(self, *args, ring: bool = False, **kwargs):
+        super().__init__(*args, **kwargs)
+        self.ring = ring
 
     def lex_argmin_nodes(self, keys, mask, gids):
         lidx, lfound = lex_argmin(keys, mask)
@@ -56,8 +58,21 @@ class PallasHierarchicalDist(HierarchicalDist):
         cfound = jax.lax.all_gather(lfound, self.chip_axis)
         cgid = jax.lax.all_gather(gids[lidx], self.chip_axis)
         hidx, hfound = lex_argmin(ckeys, cfound)
-        # DCN: one winner tuple per host, reduced by the pallas tree
-        # kernel instead of argmin over the gathered block.
+        if self.ring:
+            # DCN: this host's winner tuple travels the ring of hosts.
+            sent = jnp.int32(2**31 - 1)
+            row = jnp.stack(
+                [jnp.where(hfound, 0, 1).astype(jnp.int32)]
+                + [jnp.where(hfound, k[hidx], sent).astype(jnp.int32)
+                   for k in ckeys]
+                + [cgid[hidx].astype(jnp.int32)]
+            )
+            best = pk.ring_winner_exchange(row, self.host_axis, self.n_hosts)
+            pk._book_winner(self, self.n_hosts, len(keys))
+            wgid, wfound = best[-1], best[0] == 0
+            return jnp.where(wfound, wgid, 0).astype(jnp.int32), wfound
+        # DCN: one winner tuple per host, reduced by the pallas kernel
+        # instead of argmin over the gathered block.
         gkeys = [jax.lax.all_gather(k[hidx], self.host_axis) for k in ckeys]
         gfound = jax.lax.all_gather(hfound, self.host_axis)
         ggid = jax.lax.all_gather(cgid[hidx], self.host_axis)

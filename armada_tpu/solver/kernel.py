@@ -41,7 +41,7 @@ import numpy as np
 from ..core.config import HOT_WINDOW_MIN_SLOTS_DEFAULT
 from ..core.priorities import EVICTED_PRIORITY, MIN_PRIORITY
 from ..ops.bitset import bits_subset
-from ..ops.select import lex_argmin, masked_lexsort
+from ..ops.select import lex_argmin, lexsort, masked_lexsort
 from .dist import LOCAL
 from .kernel_prep import DeviceRound, _pow2
 
@@ -87,6 +87,15 @@ class Carry(NamedTuple):
 
 def _f(x):
     return jnp.asarray(x, jnp.result_type(float))
+
+
+def _exact_cumsum(x, axis):
+    """Prefix sums of integer-valued float64 request totals. Exact in
+    any association (every partial sum is an integer below 2**53), so a
+    log-depth scan equals `jnp.cumsum` bit for bit — and XLA:TPU compiles
+    it in seconds, where its float64 cumsum (an emulated 64-bit
+    reduce-window) took 300 s at 20k entries."""
+    return jax.lax.associative_scan(jnp.add, x, axis=axis)
 
 
 def _pack_fill_keys(dev, dist, n_local, keys):
@@ -225,7 +234,7 @@ def _policy_fair_shares(dev, demand_costs, total_is_zero):
         # Serve whole demands in descending-weight order (name-rank
         # tiebreak); sequential single-accumulator loop matches the
         # host mirror's float association exactly.
-        order = jnp.lexsort((dev.queue_name_rank, -w))
+        order = lexsort([-w, dev.queue_name_rank])
 
         def body(i, state):
             capped, uncapped, cum_prev = state
@@ -337,19 +346,22 @@ def _fair_preemption(dev, dist, carry, j, static_ok, fp_order):
     order = fp_order
     n_sorted = node[order]
     a_sorted = active[order]
-    contrib = jnp.where(a_sorted[:, None], dev.job_req_fit[order], 0).astype(
-        jnp.result_type(int)
-    )
-    c = jnp.cumsum(contrib, axis=0)
+    contrib = jnp.where(a_sorted[:, None], dev.job_req_fit[order], 0)
     pos = jnp.arange(node.shape[0])
     is_first = jnp.concatenate(
         [jnp.ones(1, bool), n_sorted[1:] != n_sorted[:-1]]
     )
-    seg_first = jax.lax.associative_scan(
-        jnp.maximum, jnp.where(is_first, pos, 0)
-    )
-    base = c[seg_first] - contrib[seg_first]
-    cwithin = c - base
+    # Per-node (segmented) int32 prefix sums. Exact: the jobs bound to
+    # one node never request more than its int32 capacity. A global
+    # int64 cumsum here is a 64-bit reduce-window that XLA:TPU refuses
+    # (scoped VMEM exhausted) at any width.
+    def seg_add(a, b):
+        fa, va = a
+        fb, vb = b
+        return fa | fb, jnp.where(fb[:, None], vb, va + vb)
+
+    _, cwithin = jax.lax.associative_scan(seg_add, (is_first, contrib))
+    cwithin = cwithin.astype(jnp.result_type(int))
     safe_node = jnp.clip(n_sorted, 0, dist.num_nodes(carry.alloc) - 1)
     avail = (
         dist.take_rows(carry.alloc[0], safe_node).astype(jnp.result_type(int))
@@ -1362,7 +1374,7 @@ def _pass_segment(
         qrow = flat_idx // W
         pos_f = jnp.broadcast_to(ivec[None, :], (Q, W)).reshape(-1)
         grp_f = grp.reshape(-1)
-        order_g = jnp.lexsort((pos_f, grp_f, qrow))
+        order_g = lexsort([qrow, grp_f, pos_f])
         q_s = qrow[order_g]
         g_s = grp_f[order_g]
         p_s = pos_f[order_g]
@@ -1397,7 +1409,7 @@ def _pass_segment(
         # closed form: entry i's queue allocation is qalloc + sum of the
         # i previous window requests).
         req_e = jnp.where(base[:, :, None], _f(dev.slot_req[widx]), 0.0)
-        csum_incl = jnp.cumsum(req_e, axis=1)  # [Q, W, R]
+        csum_incl = _exact_cumsum(req_e, axis=1)  # [Q, W, R]
         csum_prev = csum_incl - req_e
         qa = c.qalloc + _f(dev.queue_short_penalty)  # [Q, R]
         w = jnp.maximum(dev.queue_weight, 1e-12)
@@ -1468,7 +1480,7 @@ def _pass_segment(
         flat_keys = [k.reshape(-1) for k in ekeys] + [
             jnp.broadcast_to(ivec[None, :], (Q, W)).reshape(-1)
         ]
-        order = jnp.lexsort(tuple(reversed(flat_keys)))
+        order = lexsort(flat_keys)
         take = entry_ok.reshape(-1)[order]
         qidx = (jnp.arange(Q * W, dtype=jnp.int32) // W)[order]
         req_s = req_e.reshape(Q * W, -1)[order]  # [QW, R]
@@ -1481,8 +1493,8 @@ def _pass_segment(
         cum_cnt_b = jnp.cumsum(consuming.astype(jnp.int32)) - consuming.astype(
             jnp.int32
         )
-        cum_req = jnp.cumsum(req_taken, axis=0)
-        cum_req_c = jnp.cumsum(req_consumed, axis=0)
+        cum_req = _exact_cumsum(req_taken, axis=0)
+        cum_req_c = _exact_cumsum(req_consumed, axis=0)
         cum_req_cb = cum_req_c - req_consumed
         tok_ok_g = ((c.tokens - cum_cnt_b.astype(fdt)) >= 1) | ev_flat
         round_ok_g = (

@@ -303,3 +303,30 @@ def test_background_task_manager():
     _t.sleep(0.05)
     assert mgr2.stop_all(timeout=0.2) == ["stuck"]
     release.set()
+
+
+def test_compile_cache_dir_honours_env(monkeypatch, tmp_path):
+    """Where JAX_COMPILATION_CACHE_DIR is set, the program uses it and
+    sets no other directory."""
+    import jax
+
+    from armada_tpu.utils import platform as plat
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert plat.compile_cache_dir() == str(tmp_path)
+    before = jax.config.jax_compilation_cache_dir
+    plat.enable_persistent_compile_cache()
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_dir_default_is_fixed(monkeypatch):
+    """Unset, the cache is `<repo>/.jax_cache` — the same path on every
+    host and run, so a later process finds what an earlier one wrote."""
+    import os
+
+    from armada_tpu.utils import platform as plat
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert plat.compile_cache_dir() == os.path.join(repo, ".jax_cache")
+    assert plat.compile_cache_dir() == plat.compile_cache_dir()

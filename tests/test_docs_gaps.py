@@ -99,7 +99,7 @@ def _feature_exists_window_autotune() -> bool:
 
 def _feature_exists_native_ring_test() -> bool:
     # Closed once the native ICI ring runs on real hardware in a test:
-    # the promoted form of tools/pallas_probe.py's native smoke.
+    # the promoted form of chip_smoke.py's mesh phase.
     path = os.path.join(REPO, "tests", "test_pallas_parity.py")
     if not os.path.exists(path):
         return False

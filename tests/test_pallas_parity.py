@@ -15,8 +15,8 @@ The armada_tpu/ops/pallas_kernels.py contract asserted on CPU:
   hardware isn't.
 
 Every pallas kernel here runs under interpret=True (no TPU attached in
-tier-1); the native path is covered by tools/pallas_probe.py on real
-hardware (docs/known_gaps.yaml: pallas-ici-native).
+tier-1); tests/test_tpu_compile.py compiles them for a described v5e and
+chip_smoke.py runs them natively (docs/known_gaps.yaml: pallas-ici-native).
 """
 
 import dataclasses
@@ -133,18 +133,50 @@ def test_winner_reduce_none_found():
 # ---------------------------------------------------------------------------
 
 
+@pytest.mark.parametrize("kinds", [("i64",), ("f64", "i32"), ("u64", "f32", "bool"), ("f64", "f64", "i64")])
+def test_lexsort_32bit_words_match_jnp_lexsort(kinds):
+    """ops/select.lexsort splits 64-bit keys into 32-bit words (XLA:TPU
+    compiles 64-bit multi-key sorts for minutes); it must order exactly
+    as jnp.lexsort, including NaN, -0.0, infinities and ties."""
+    from armada_tpu.ops.select import lexsort
+
+    rng = np.random.default_rng(len(kinds))
+    n = 500
+    special = [-np.inf, np.inf, np.nan, -0.0, 0.0, 1 / 3, 1 / 3 + 2**-52, -2.5]
+    make = {
+        "i64": lambda: rng.integers(-3, 3, n) * 2**40 + rng.integers(-2, 2, n),
+        "u64": lambda: rng.integers(0, 2**63, n, dtype=np.uint64) | (
+            rng.integers(0, 2, n, dtype=np.uint64) << np.uint64(63)
+        ),
+        "i32": lambda: rng.integers(-5, 5, n).astype(np.int32),
+        "f32": lambda: rng.choice(special, n).astype(np.float32),
+        "f64": lambda: np.where(
+            rng.random(n) < 0.5,
+            rng.choice(special, n),
+            rng.random(n) * rng.choice([1e-20, 1.0, 1e20], n),
+        ),
+        "bool": lambda: rng.integers(0, 2, n).astype(bool),
+    }
+    keys = [jnp.asarray(make[k]()) for k in kinds]
+    want = np.asarray(jnp.lexsort(tuple(reversed(keys))))
+    np.testing.assert_array_equal(np.asarray(lexsort(keys)), want)
+
+
 def test_resolve_kernel_path(monkeypatch):
     monkeypatch.delenv(pk.PATH_ENV, raising=False)
     assert pk.resolve_kernel_path("blocked") == "blocked"
-    # Unknown config values fall back instead of raising.
-    assert pk.resolve_kernel_path("tpuv9") == "lax"
-    # native demotes to pallas interpret off-hardware (no TPU in tier-1).
-    assert pk.resolve_kernel_path("native") == "pallas"
+    # A path that cannot run raises; nothing switches to another path.
+    with pytest.raises(ValueError, match="tpuv9"):
+        pk.resolve_kernel_path("tpuv9")
+    # native needs a TPU (tier-1 runs on the CPU).
+    with pytest.raises(RuntimeError, match="needs a TPU"):
+        pk.resolve_kernel_path("native")
     # Env is the A/B lever and beats config.
     monkeypatch.setenv(pk.PATH_ENV, "pallas")
     assert pk.resolve_kernel_path("lax") == "pallas"
     monkeypatch.setenv(pk.PATH_ENV, "bogus")
-    assert pk.resolve_kernel_path("blocked") == "blocked"
+    with pytest.raises(ValueError, match="bogus"):
+        pk.resolve_kernel_path("blocked")
 
 
 def test_config_rejects_unknown_kernel_path():

@@ -74,9 +74,9 @@ def main(argv=None) -> int:
 
     # Match the production solver configuration BEFORE any jax-touching
     # import (same preamble as tools/replay_gate.py).
-    from armada_tpu.utils.platform import ensure_healthy_backend
+    from armada_tpu.utils.platform import setup_jax
 
-    ensure_healthy_backend()
+    setup_jax()
 
     from armada_tpu.autotune import TuningStore, default_grid, tune_corpus
     from armada_tpu.autotune.offline import DEFAULT_WINDOWS

@@ -71,12 +71,12 @@ def main(argv=None) -> int:
                     help="emit the full report as one JSON line")
     args = ap.parse_args(argv)
 
-    # Match the production solver configuration (x64 exact costs, healthy
-    # backend) BEFORE any jax-touching import: an x64 mismatch against an
+    # Match the production solver configuration (x64 exact costs)
+    # BEFORE any jax-touching import: an x64 mismatch against an
     # x64-recorded bundle is a guaranteed target refusal.
-    from armada_tpu.utils.platform import ensure_healthy_backend
+    from armada_tpu.utils.platform import setup_jax
 
-    ensure_healthy_backend()
+    setup_jax()
 
     from armada_tpu.trace import (
         TraceFormatError,
