@@ -911,9 +911,10 @@ class SchedulerService:
         # away pools, so id-exclusion alone would double-book nodes).
         pending_leases: dict[str, tuple] = {}
         for pool in sorted(pools):
-            # Per-pool round span: the solve profile (setup/pass1/gather/
-            # finish) lands as child spans from _solve; the summary attrs
-            # are set on this span when the round completes.
+            # Per-pool round span: the round's stages (round.snapshot,
+            # residency.sync, solve, round.fairness/validate/record/
+            # commit) nest under it as they run; the summary attrs are
+            # set on this span when the round completes.
             with self._span("scheduler.round", pool=pool,
                             cycle=self.cycle_count):
                 pool_seqs = self._schedule_pool(
@@ -1367,57 +1368,59 @@ class SchedulerService:
     ) -> list[EventSequence]:
         inc = None
         t_build = _time.monotonic()
-        txn = self.jobdb.read_txn()
-        if self._cycle_incremental_ok and not exclude and not pending_leases:
-            inc = self._incremental_round(
-                pool, now, executors, overrides, skipped, cordoned, txn
-            )
-        if inc is not None:
-            st = self._inc_state[pool]
-            node_executor = st["node_executor"]
-            g_tokens, q_tokens = st["tokens"]
-            if not st["node_executor"] or inc._size == len(inc._free):
-                # Idle round: persist the refilled buckets anyway —
-                # _refill_rate_tokens already advanced the refill clock,
-                # so dropping them would freeze depleted buckets for the
-                # whole idle stretch.
-                self._rate_tokens[pool] = g_tokens
-                for qn, tokens in q_tokens.items():
-                    self._queue_rate_tokens[(pool, qn)] = tokens
-                return []
-            snap = inc.snapshot()
-        else:
-            (
-                nodes,
-                queues,
-                running,
-                queued,
-                node_executor,
-                txn,
-                excluded_nodes,
-            ) = self._build_pool_inputs(
-                pool, exclude, executors, overrides, skipped, pending_leases
-            )
-            if not nodes or not (queued or running):
-                return []
-            g_tokens, q_tokens = self._refill_rate_tokens(
-                pool, now, [q.name for q in queues]
-            )
-            snap = build_round_snapshot(
-                self.config,
-                pool,
-                nodes,
-                queues,
-                running,
-                queued,
-                excluded_nodes=excluded_nodes,
-                cordoned_queues=(
-                    cordoned if cordoned is not None else self.cordoned_queues
-                ),
-                short_job_penalty=self._short_job_penalties(txn, pool, now),
-                global_rate_tokens=g_tokens,
-                queue_rate_tokens=q_tokens,
-            )
+        with self._span("round.snapshot"):
+            txn = self.jobdb.read_txn()
+            if self._cycle_incremental_ok and not exclude and not pending_leases:
+                inc = self._incremental_round(
+                    pool, now, executors, overrides, skipped, cordoned, txn
+                )
+            if inc is not None:
+                st = self._inc_state[pool]
+                node_executor = st["node_executor"]
+                g_tokens, q_tokens = st["tokens"]
+                if not st["node_executor"] or inc._size == len(inc._free):
+                    # Idle round: persist the refilled buckets anyway —
+                    # _refill_rate_tokens already advanced the refill
+                    # clock, so dropping them would freeze depleted
+                    # buckets for the whole idle stretch.
+                    self._rate_tokens[pool] = g_tokens
+                    for qn, tokens in q_tokens.items():
+                        self._queue_rate_tokens[(pool, qn)] = tokens
+                    return []
+                snap = inc.snapshot()
+            else:
+                (
+                    nodes,
+                    queues,
+                    running,
+                    queued,
+                    node_executor,
+                    txn,
+                    excluded_nodes,
+                ) = self._build_pool_inputs(
+                    pool, exclude, executors, overrides, skipped, pending_leases
+                )
+                if not nodes or not (queued or running):
+                    return []
+                g_tokens, q_tokens = self._refill_rate_tokens(
+                    pool, now, [q.name for q in queues]
+                )
+                snap = build_round_snapshot(
+                    self.config,
+                    pool,
+                    nodes,
+                    queues,
+                    running,
+                    queued,
+                    excluded_nodes=excluded_nodes,
+                    cordoned_queues=(
+                        cordoned if cordoned is not None
+                        else self.cordoned_queues
+                    ),
+                    short_job_penalty=self._short_job_penalties(txn, pool, now),
+                    global_rate_tokens=g_tokens,
+                    queue_rate_tokens=q_tokens,
+                )
         # Device-resident round state (snapshot/residency.py): keep the
         # padded DeviceRound on device across warm cycles and delta-sync
         # it in _attempt_round. Mesh solves re-pad and re-place the node
@@ -1639,11 +1642,21 @@ class SchedulerService:
             preempted=self.last_cycle_stats["preempted"],
             solve_s=round(_time.time() - solve_started, 4),
         ).info("scheduling round complete")
-        self._record_round(
-            pool, snap, result, solve_started, indicative,
-            idealised=idealised, realised=realised, now=now,
-        )
+        with self._span("round.record"):
+            self._record_round(
+                pool, snap, result, solve_started, indicative,
+                idealised=idealised, realised=realised, now=now,
+            )
+        with self._span("round.commit"):
+            return self._commit_events(
+                pool, now, snap, result, txn, node_executor, pending_leases
+            )
 
+    def _commit_events(self, pool, now, snap, result, txn, node_executor,
+                       pending_leases) -> list[EventSequence]:
+        """The round's decisions as event sequences: a JobRunLeased per
+        scheduled job, an attributed JobRunPreempted per preemption, each
+        jobset's sequence carrying its submit trace."""
         by_jobset: dict[tuple, list] = {}
         import numpy as np
 
@@ -1828,45 +1841,41 @@ class SchedulerService:
                         int(compiles[key])
                     )
 
-    def _emit_solve_spans(self, pool: str, profile: dict | None,
-                          solve_s: float, transfer: dict | None = None,
-                          compiles: dict | None = None):
-        """Child spans of the open round span for the hot-window solve
-        profile: setup/pass1/gather/finish laid out sequentially over
-        the measured solve window, plus the loop mix and rewindow count
-        as attrs on the round span itself — so a Perfetto view of the
-        exported spans shows WHERE a round spent its time. The transfer
-        ledger and compile delta ride as round-span attrs: the Perfetto
-        view answers "is it churn or solve" without leaving the trace."""
+    def _annotate_round_span(self, profile: dict | None, solve_s: float,
+                             transfer: dict | None = None,
+                             compiles: dict | None = None):
+        """The solve's summary as attrs on the open round span: solve
+        seconds, backend, the transfer ledger and compile delta (so a
+        Perfetto view answers "is it churn or solve" without leaving the
+        trace), and the loop mix and rewindows of a host-driven solve.
+        The solve's own spans (solver/kernel.solve_round) nest under the
+        round span as they happen."""
         tracer = self.tracer
         if tracer is None:
             return
         parent = tracer.current_span()
-        if parent is not None and parent.name == "scheduler.round":
-            parent.attrs.update(
-                solve_s=round(solve_s, 4),
-                backend=self.backend,
-            )
-            if transfer:
-                parent.attrs.update(
-                    transfer_bytes_up=int(transfer.get("bytes_up", 0)),
-                    transfer_bytes_down=int(transfer.get("bytes_down", 0)),
-                    transfer_donated_bytes=int(
-                        transfer.get("donated_bytes", 0)
-                    ),
-                    transfer_donated_buffers=int(
-                        transfer.get("donated_buffers", 0)
-                    ),
-                )
-            if compiles:
-                parent.attrs.update(
-                    xla_compiles=int(compiles.get("compiles", 0)),
-                    xla_retraces=int(compiles.get("traces", 0)),
-                    xla_compile_s=float(compiles.get("compile_seconds", 0.0)),
-                )
-        if not profile:
+        if parent is None or parent.name != "scheduler.round":
             return
-        if parent is not None:
+        parent.attrs.update(
+            solve_s=round(solve_s, 4),
+            backend=self.backend,
+        )
+        if transfer:
+            parent.attrs.update(
+                transfer_bytes_up=int(transfer.get("bytes_up", 0)),
+                transfer_bytes_down=int(transfer.get("bytes_down", 0)),
+                transfer_donated_bytes=int(transfer.get("donated_bytes", 0)),
+                transfer_donated_buffers=int(
+                    transfer.get("donated_buffers", 0)
+                ),
+            )
+        if compiles:
+            parent.attrs.update(
+                xla_compiles=int(compiles.get("compiles", 0)),
+                xla_retraces=int(compiles.get("traces", 0)),
+                xla_compile_s=float(compiles.get("compile_seconds", 0.0)),
+            )
+        if profile:
             parent.attrs.update(
                 gang_loops=profile.get("gang_loops", 0),
                 fill_loops=profile.get("fill_loops", 0),
@@ -1874,14 +1883,6 @@ class SchedulerService:
                 rewindows=profile.get("rewindows", 0),
                 window_slots=profile.get("window_slots", 0),
             )
-        import time as _t
-
-        from ..utils.tracing import add_segment_spans
-
-        add_segment_spans(
-            tracer, parent, _t.time_ns() - int(solve_s * 1e9), profile,
-            pool=pool,
-        )
 
     def _note_solve_profile(self, pool: str, profile: dict | None):
         """Per-segment solve timings + pass-1 loop mix from the
@@ -2626,7 +2627,7 @@ class SchedulerService:
             # the scheduler-round ledger (covers mesh placement AND the
             # solve's own books) plus the compile delta. The same
             # numbers land in metrics (_note_transfer), the round span
-            # (_emit_solve_spans) and the flight-recorder record — so
+            # (_annotate_round_span) and the flight-recorder record — so
             # replay can diff cost, not just decisions.
             transfer = _led.as_dict()
             compiles = _xla.delta_since(_comp0, thread=True)
@@ -2646,9 +2647,10 @@ class SchedulerService:
                 try:
                     from ..observe.fairness import ledger_from_device_round
 
-                    fairness_block = ledger_from_device_round(
-                        dev_host, out, snap.num_jobs, snap.num_queues
-                    )
+                    with self._span("round.fairness"):
+                        fairness_block = ledger_from_device_round(
+                            dev_host, out, snap.num_jobs, snap.num_queues
+                        )
                 except Exception as e:  # noqa: BLE001 - advisory path
                     self.log_.with_fields(pool=snap.pool).error(
                         "fairness ledger failed: %r", e
@@ -2663,9 +2665,10 @@ class SchedulerService:
                 from ..solver.validate import RoundRejected, validate_round
 
                 t_v = _t.monotonic()
-                violation = validate_round(
-                    out, dev=dev_host, fairness=fairness_block
-                )
+                with self._span("round.validate"):
+                    violation = validate_round(
+                        out, dev=dev_host, fairness=fairness_block
+                    )
                 cost_profile["validate_s"] = round(_t.monotonic() - t_v, 6)
                 if violation is not None:
                     bundle = None
@@ -2677,38 +2680,40 @@ class SchedulerService:
             if "profile" in out:
                 out["profile"] = cost_profile
             if not shadow:
-                self._note_transfer(snap.pool, transfer, compiles)
-                self._note_solve_kernel(
-                    snap.pool, str(solver_info.get("kernel") or "lax")
-                )
-                if self.trace_recorder is not None:
-                    self._trace_round(
-                        snap,
-                        dev_host,
-                        out,
-                        solver=solver_info,
-                        truncated=truncated,
-                        solve_s=round(_t.monotonic() - t_solve, 4),
-                        profile=cost_profile,
-                        fairness=fairness_block,
+                with self._span("round.record"):
+                    self._note_transfer(snap.pool, transfer, compiles)
+                    self._note_solve_kernel(
+                        snap.pool, str(solver_info.get("kernel") or "lax")
                     )
-                self._note_solve_profile(snap.pool, out.get("profile"))
-                if self.autotune is not None and rung.kind == "local":
-                    # Between-rounds adjustment. Only rounds the
-                    # single-device kernel actually solved on its tuned
-                    # parameters feed the loop: the sharded (mesh) solve
-                    # takes no window vector, and a hotwindow fallback
-                    # round ran a forced degraded window — either would
-                    # read as a false disengagement signal.
-                    self.autotune.observe_round(
-                        snap.pool,
-                        out.get("profile"),
-                        solve_s=_t.monotonic() - t_solve,
-                        metrics=self.metrics,
-                        log=self.log_,
-                    )
-                self._emit_solve_spans(
-                    snap.pool, out.get("profile"), _t.monotonic() - t_solve,
+                    if self.trace_recorder is not None:
+                        self._trace_round(
+                            snap,
+                            dev_host,
+                            out,
+                            solver=solver_info,
+                            truncated=truncated,
+                            solve_s=round(_t.monotonic() - t_solve, 4),
+                            profile=cost_profile,
+                            fairness=fairness_block,
+                        )
+                    self._note_solve_profile(snap.pool, out.get("profile"))
+                    if self.autotune is not None and rung.kind == "local":
+                        # Between-rounds adjustment. Only rounds the
+                        # single-device kernel actually solved on its
+                        # tuned parameters feed the loop: the sharded
+                        # (mesh) solve takes no window vector, and a
+                        # hotwindow fallback round ran a forced degraded
+                        # window — either would read as a false
+                        # disengagement signal.
+                        self.autotune.observe_round(
+                            snap.pool,
+                            out.get("profile"),
+                            solve_s=_t.monotonic() - t_solve,
+                            metrics=self.metrics,
+                            log=self.log_,
+                        )
+                self._annotate_round_span(
+                    out.get("profile"), _t.monotonic() - t_solve,
                     transfer=transfer, compiles=compiles,
                 )
             J, Q = snap.num_jobs, snap.num_queues
@@ -2762,12 +2767,13 @@ class SchedulerService:
             # run only on kernel rungs.
             from ..solver.validate import RoundRejected, validate_round
 
-            violation = validate_round(
-                result,
-                num_jobs=snap.num_jobs,
-                num_nodes=len(snap.node_ids),
-                job_is_running=snap.job_is_running,
-            )
+            with self._span("round.validate"):
+                violation = validate_round(
+                    result,
+                    num_jobs=snap.num_jobs,
+                    num_nodes=len(snap.node_ids),
+                    job_is_running=snap.job_is_running,
+                )
             if violation is not None:
                 bundle = None
                 if not shadow:
@@ -2825,7 +2831,7 @@ class SchedulerService:
         # result["fairness"] None: _record_round computes the host-unit
         # ledger_from_snapshot fallback for the live surfaces.
         if not shadow:
-            self._emit_solve_spans(snap.pool, None, _t.monotonic() - t_solve)
+            self._annotate_round_span(None, _t.monotonic() - t_solve)
         return result
 
     def _decorate_fairness(self, snap, fairness: dict) -> dict:

@@ -59,6 +59,7 @@ from ..solver.kernel_prep import (
     DeviceRound,
     pad_device_round,
 )
+from ..utils import tracing
 
 _DATA_FIELDS = tuple(
     f.name for f in dataclasses.fields(DeviceRound) if f.name not in _META_FIELDS
@@ -195,16 +196,28 @@ class ResidentRound:
         """The device-resident padded round for `inc`'s current
         generation, synced via delta scatter (or full reset). Call
         inside the round's transfer ledger: every byte that actually
-        travels host→device books here and nowhere else."""
+        travels host→device books here and nowhere else.
+
+        A sync that does work is a `residency.sync` span (attrs `mode`,
+        `bytes_up`, `fields` from `last_sync`) with children
+        `residency.prep` (the padded host round), `residency.diff` and
+        `residency.upload` (utils/tracing.span)."""
         gen = getattr(inc, "_gen", None)
         if self._dev is not None and self._inc is inc and gen == self._gen:
             return self._dev
-        new = pad_device_round(inc.device_round())
-        if self._host is None or not self._compatible(new):
-            self._full_reset(new)
-        else:
-            self._delta_sync(new)
-        self._inc, self._gen = inc, gen
+        with tracing.span("residency.sync") as sync:
+            with tracing.span("residency.prep"):
+                new = pad_device_round(inc.device_round())
+            if self._host is None or not self._compatible(new):
+                self._full_reset(new)
+            else:
+                self._delta_sync(new)
+            self._inc, self._gen = inc, gen
+            sync.attrs.update(
+                mode=self.last_sync["mode"],
+                bytes_up=self.last_sync["bytes_up"],
+                fields=self.last_sync["fields"],
+            )
         return self._dev
 
     def check_drift(self) -> list[str]:
@@ -251,26 +264,27 @@ class ResidentRound:
         host: dict = {}
         dev: dict = {}
         bytes_up = 0
-        for name in _DATA_FIELDS:
-            v = getattr(new, name)
-            if isinstance(v, np.ndarray) and v.ndim >= 1:
-                # Own the mirror: prep_device_round hands out views of
-                # the IncrementalRound's live columnar arrays, which the
-                # next delta mutates in place.
-                owned = np.ascontiguousarray(v)
-                if owned is v:
-                    owned = v.copy()
-                _tledger.note_up(owned, site="residency.reset")
-                bytes_up += owned.nbytes
-                host[name] = owned
-                dev[name] = jax.device_put(owned)
-            else:
-                # Scalar leaves (global_tokens, spot_price_cutoff, ...)
-                # stay host-side: jit canonicalizes them at dispatch
-                # exactly as on the rebuild path, keeping the compiled
-                # program and its dtype handling identical.
-                host[name] = v
-                dev[name] = v
+        with tracing.span("residency.upload"):
+            for name in _DATA_FIELDS:
+                v = getattr(new, name)
+                if isinstance(v, np.ndarray) and v.ndim >= 1:
+                    # Own the mirror: prep_device_round hands out views of
+                    # the IncrementalRound's live columnar arrays, which
+                    # the next delta mutates in place.
+                    owned = np.ascontiguousarray(v)
+                    if owned is v:
+                        owned = v.copy()
+                    _tledger.note_up(owned, site="residency.reset")
+                    bytes_up += owned.nbytes
+                    host[name] = owned
+                    dev[name] = jax.device_put(owned)
+                else:
+                    # Scalar leaves (global_tokens, spot_price_cutoff,
+                    # ...) stay host-side: jit canonicalizes them at
+                    # dispatch exactly as on the rebuild path, keeping the
+                    # compiled program and its dtype handling identical.
+                    host[name] = v
+                    dev[name] = v
         self._host = dataclasses.replace(new, **host)
         self._dev = dataclasses.replace(new, **dev)
         self.last_sync = {
@@ -305,24 +319,65 @@ class ResidentRound:
         return source
 
     def _delta_sync(self, new: DeviceRound):
+        """Host diffing and device work overlap, each phase a span:
+        `residency.diff` finds the slot source map; `residency.upload`
+        dispatches the slot permutation, which the device runs (a quarter
+        of a second at a million slots) while the host goes on;
+        `residency.diff` permutes the host mirror the same way and finds
+        each field's changed rows; `residency.upload` transfers and
+        dispatches the row scatters and whole-field replacements."""
         import jax
 
         bytes_up = 0
-        touched: list[str] = []
-        source = self._slot_source_map(new)
+        with tracing.span("residency.diff"):
+            source = self._slot_source_map(new)
         if source is not None:
-            # One uploaded source map permutes every slot-axis field on
-            # device; the host mirror permutes identically, so the
+            with tracing.span("residency.upload"):
+                # One uploaded source map permutes every slot-axis field
+                # on device.
+                _tledger.note_up(source, site="residency.slot_map")
+                bytes_up += source.nbytes
+                source_dev = jax.device_put(source)
+                gather = _jit_for("g0")
+                for name in _SLOT_FIELDS:
+                    setattr(
+                        self._dev, name,
+                        gather(getattr(self._dev, name), source_dev),
+                    )
+        with tracing.span("residency.diff"):
+            updates, touched = self._diff(new, source)
+        with tracing.span("residency.upload"):
+            for name, axis, payload in updates:
+                if axis is None:
+                    # The batch would outweigh the field: replace wholesale.
+                    _tledger.note_up(payload, site="residency.full")
+                    bytes_up += payload.nbytes
+                    setattr(self._dev, name, jax.device_put(payload))
+                    continue
+                idx, vals = payload
+                _tledger.note_up(payload, site="residency.delta")
+                bytes_up += idx.nbytes + vals.nbytes
+                fn = _jit_for("s1" if axis == 1 else "s0")
+                setattr(self._dev, name, fn(getattr(self._dev, name), idx, vals))
+        self.last_sync = {
+            "mode": "delta",
+            "bytes_up": int(bytes_up),
+            "fields": touched,
+            "permuted": source is not None,
+        }
+
+    def _diff(self, new: DeviceRound, source):
+        """Bring the host mirror to `new` and say what the device needs
+        beyond the slot permutation `source` (or None): ([(field, axis,
+        (idx, vals))] row scatters and [(field, None, array)] whole-field
+        replacements, touched field names). Scalar leaves are set on both
+        sides here."""
+        touched: list[str] = []
+        updates: list[tuple] = []
+        if source is not None:
+            # The host mirror permutes as the device does, so the
             # residual diff below only sees true content changes.
-            _tledger.note_up(source, site="residency.slot_map")
-            bytes_up += source.nbytes
-            source_dev = jax.device_put(source)
-            gather = _jit_for("g0")
             for name in _SLOT_FIELDS:
-                setattr(
-                    self._dev, name,
-                    gather(getattr(self._dev, name), source_dev),
-                )
                 h = getattr(self._host, name)
                 setattr(self._host, name, np.ascontiguousarray(h[source]))
         for name in _DATA_FIELDS:
@@ -343,14 +398,11 @@ class ResidentRound:
             row_bytes = max(1, cur.nbytes // cur.shape[axis])
             kb = _bucket(int(rows.size))
             if kb * (4 + row_bytes) >= cur.nbytes:
-                # The batch would outweigh the field: replace wholesale.
                 owned = np.ascontiguousarray(nxt)
                 if owned is nxt:
                     owned = nxt.copy()
-                _tledger.note_up(owned, site="residency.full")
-                bytes_up += owned.nbytes
-                setattr(self._dev, name, jax.device_put(owned))
                 setattr(self._host, name, owned)
+                updates.append((name, None, owned))
                 continue
             # Bucket-pad by repeating a real index with its own row:
             # duplicate indices write duplicate values, so the scatter
@@ -359,20 +411,12 @@ class ResidentRound:
             idx[: rows.size] = rows
             idx[rows.size:] = rows[0]
             vals = np.ascontiguousarray(np.take(nxt, idx, axis=axis))
-            _tledger.note_up((idx, vals), site="residency.delta")
-            bytes_up += idx.nbytes + vals.nbytes
-            fn = _jit_for("s1" if axis == 1 else "s0")
-            setattr(self._dev, name, fn(getattr(self._dev, name), idx, vals))
+            updates.append((name, axis, (idx, vals)))
             if axis == 1:
                 cur[:, rows] = nxt[:, rows]
             else:
                 cur[rows] = nxt[rows]
-        self.last_sync = {
-            "mode": "delta",
-            "bytes_up": int(bytes_up),
-            "fields": touched,
-            "permuted": source is not None,
-        }
+        return updates, touched
 
     @staticmethod
     def _scalar_equal(a, b) -> bool:

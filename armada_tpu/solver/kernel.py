@@ -42,6 +42,7 @@ from ..core.config import HOT_WINDOW_MIN_SLOTS_DEFAULT
 from ..core.priorities import EVICTED_PRIORITY, MIN_PRIORITY
 from ..ops.bitset import bits_subset
 from ..ops.select import lex_argmin, lexsort, masked_lexsort
+from ..utils import tracing
 from .dist import LOCAL
 from .kernel_prep import DeviceRound, _pow2
 
@@ -2395,6 +2396,16 @@ def solve_round(
     segment (setup / pass-1 / gather+scatter / finish) and pass-1 loop
     counts by kind (gang / fill / merged-fill), plus rewindow counts.
 
+    Spans (utils/tracing.span, nested under the caller's open span): a
+    `solve` span with children `solve.fused` + `solve.readback` on the
+    fused path, and `solve.setup`, one `solve.pass1` per chunk run (per
+    window run when compacted), `solve.gather` per window gather and per
+    scatter-back, `solve.finish` and `solve.readback` on the host-driven
+    one. The profile's `*_s` values are those spans' durations. The
+    `solve` span carries `host_syncs` (every block_until_ready or
+    np.asarray the driver makes before the readback), the rewindows and
+    the loop counts.
+
     readback_rows (the unpadded live-job count) trims the device->host
     readback of the per-job decision arrays to that prefix — the padded
     tail is inert by construction and is re-expanded host-side, so
@@ -2420,41 +2431,60 @@ def solve_round(
         # ledger (observe/ledger.py) books the implicit dispatch upload
         # of the host arrays and the numpy materialization of the
         # outputs into whatever round ledger the caller activated.
-        _tledger.note_up(dev, site="solve.dispatch")
-        out = _solve(dev)
-        out, _expand = _materialize_out(out, dev, readback_rows)
-        _tledger.note_down(out, site="solve.d2h")
-        out = _expand(out)
         from .validate import maybe_assert_finite
 
-        maybe_assert_finite(out, "kernel.solve_round[fused]")
+        with tracing.span("solve", path="fused", host_syncs=1):
+            with tracing.span("solve.fused"):
+                _tledger.note_up(dev, site="solve.dispatch")
+                out = _solve(dev)
+                jax.block_until_ready(out)
+            with tracing.span("solve.readback"):
+                out, _expand = _materialize_out(out, dev, readback_rows)
+                _tledger.note_down(out, site="solve.d2h")
+                out = _expand(out)
+                maybe_assert_finite(out, "kernel.solve_round[fused]")
         return out
 
     import time as _time
 
+    syncs = 0
+
+    def _wait(x):
+        nonlocal syncs
+        syncs += 1
+        jax.block_until_ready(x)
+
+    def _host(x):
+        nonlocal syncs
+        syncs += 1
+        return np.asarray(x)
+
     # Per-solve transfer ledger: the host-driven driver attaches its own
     # complete up/down/donated accounting to out["profile"]["transfer"]
     # (notes also book into any outer, e.g. scheduler-round, ledger).
-    with _tledger.round_ledger() as _led:
+    with _tledger.round_ledger() as _led, tracing.span(
+        "solve", path="host"
+    ) as solve_span:
         deadline = _time.monotonic() + float(budget_s) if use_budget else None
-        # One upload: every chunk reuses the resident round tensors
-        # instead of re-transferring the host arrays per segment.
-        _tledger.note_up(dev, site="solve.h2d")
-        dev = jax.device_put(dev)
-        t0 = _time.monotonic()
-        carry, ptr, budgets, fair_share, demand_capped, uncapped = _pass1_begin(dev)
-        jax.block_until_ready(carry.loops)
-        setup_s = _time.monotonic() - t0
+        with tracing.span("solve.setup") as setup:
+            # One upload: every chunk reuses the resident round tensors
+            # instead of re-transferring the host arrays per segment.
+            _tledger.note_up(dev, site="solve.h2d")
+            dev = jax.device_put(dev)
+            carry, ptr, budgets, fair_share, demand_capped, uncapped = (
+                _pass1_begin(dev)
+            )
+            _wait(carry.loops)
+            plan = _window_plan(dev, carry, pre)
+            syncs += pre is not None  # its evicted-count readback
         fs = jnp.zeros((), bool)
         segc = jnp.zeros(3, jnp.int32)
         S = int(dev.slot_members.shape[0])
         hard_cap = 2 * S + 4
         chunk = max(1, int(chunk_loops))
         truncated = False
-        plan = _window_plan(dev, carry, pre)
         rewindows = 0
-        gather_s = 0.0
-        t_pass = _time.monotonic()
+        pass1, gathers = [], []
 
         def _adapt_chunk(t0, executed):
             # Re-check the clock roughly every budget/8 while never batching
@@ -2465,30 +2495,44 @@ def solve_round(
             return max(1, min(int(target / max(per_loop, 1e-7)), 4096))
 
         if plan is None:
-            while True:
-                jax.block_until_ready(carry.loops)
-                loops = int(np.asarray(carry.loops))
-                if bool(np.asarray(carry.stop)) or loops >= hard_cap:
-                    break
-                # Forward-progress floor: even a budget spent before the first
-                # loop (snapshot build ate it) runs ONE loop, so a persistently
-                # tiny budget drains the backlog instead of starving it.
-                if deadline is not None and loops > 0 and _time.monotonic() >= deadline:
-                    truncated = True
-                    break
-                cap = hard_cap if deadline is None else min(loops + chunk, hard_cap)
-                t0 = _time.monotonic()
-                # The chunk donates its carries: device buffers updated
-                # in place, not re-uploaded — booked on the donated side
-                # of the ledger so the copied-vs-donated split is real.
-                _tledger.note_donated((carry, ptr, fs, segc), site="pass1.chunk")
-                carry, ptr, fs, segc = _pass1_chunk(
-                    dev, carry, ptr, fs, segc, budgets, jnp.int32(cap)
-                )
-                jax.block_until_ready(carry.loops)
-                executed = max(1, int(np.asarray(carry.loops)) - loops)
-                if deadline is not None:
-                    chunk = _adapt_chunk(t0, executed)
+            done = False
+            while not done:
+                with tracing.span("solve.pass1") as sp:
+                    pass1.append(sp)
+                    _wait(carry.loops)
+                    loops = int(_host(carry.loops))
+                    if bool(_host(carry.stop)) or loops >= hard_cap:
+                        done = True
+                    # Forward-progress floor: even a budget spent before the
+                    # first loop (snapshot build ate it) runs ONE loop, so a
+                    # persistently tiny budget drains the backlog instead of
+                    # starving it.
+                    elif (
+                        deadline is not None
+                        and loops > 0
+                        and _time.monotonic() >= deadline
+                    ):
+                        truncated = done = True
+                    else:
+                        cap = (
+                            hard_cap if deadline is None
+                            else min(loops + chunk, hard_cap)
+                        )
+                        t0 = _time.monotonic()
+                        # The chunk donates its carries: device buffers
+                        # updated in place, not re-uploaded — booked on the
+                        # donated side of the ledger so the copied-vs-donated
+                        # split is real.
+                        _tledger.note_donated(
+                            (carry, ptr, fs, segc), site="pass1.chunk"
+                        )
+                        carry, ptr, fs, segc = _pass1_chunk(
+                            dev, carry, ptr, fs, segc, budgets, jnp.int32(cap)
+                        )
+                        _wait(carry.loops)
+                        executed = max(1, int(_host(carry.loops)) - loops)
+                        if deadline is not None:
+                            chunk = _adapt_chunk(t0, executed)
         else:
             from .hotwindow import gather_window, scatter_back
 
@@ -2496,86 +2540,99 @@ def solve_round(
             Q = int(dev.queue_weight.shape[0])
             done = False
             while not done:
-                t0 = _time.monotonic()
-                ptr = _pass1_norm(dev, carry, ptr)
-                win_base = ptr
-                dev_w, carry_w, ptr_w, trunc, win_len, sidx, jidx = gather_window(
-                    dev, carry, ptr, Ws, Ep
-                )
-                trunc_np = np.asarray(trunc)
-                end_np = np.arange(Q) * Ws + np.asarray(win_len)
-                gather_s += _time.monotonic() - t0
-                while True:
-                    jax.block_until_ready(carry_w.loops)
-                    loops = int(np.asarray(carry_w.loops))
-                    stop = bool(np.asarray(carry_w.stop))
-                    short = (end_np - np.asarray(ptr_w)) < lookahead
-                    rewind = (not stop) and bool(np.any(trunc_np & short))
-                    if stop or loops >= hard_cap:
-                        done = True
-                        break
-                    if rewind:
-                        break
-                    if (
-                        deadline is not None
-                        and loops > 0
-                        and _time.monotonic() >= deadline
-                    ):
-                        truncated = True
-                        done = True
-                        break
-                    cap = hard_cap if deadline is None else min(loops + chunk, hard_cap)
-                    t0 = _time.monotonic()
-                    _tledger.note_donated(
-                        (carry_w, ptr_w, fs, segc), site="pass1.window_chunk"
+                with tracing.span("solve.gather") as sp:
+                    gathers.append(sp)
+                    ptr = _pass1_norm(dev, carry, ptr)
+                    win_base = ptr
+                    dev_w, carry_w, ptr_w, trunc, win_len, sidx, jidx = (
+                        gather_window(dev, carry, ptr, Ws, Ep)
                     )
-                    carry_w, ptr_w, fs, segc = _pass1_window_chunk(
-                        dev_w, carry_w, ptr_w, fs, segc, budgets,
-                        jnp.int32(cap), trunc,
+                    trunc_np = _host(trunc)
+                    end_np = np.arange(Q) * Ws + _host(win_len)
+                with tracing.span("solve.pass1") as sp:
+                    pass1.append(sp)
+                    while True:
+                        _wait(carry_w.loops)
+                        loops = int(_host(carry_w.loops))
+                        stop = bool(_host(carry_w.stop))
+                        short = (end_np - _host(ptr_w)) < lookahead
+                        rewind = (not stop) and bool(np.any(trunc_np & short))
+                        if stop or loops >= hard_cap:
+                            done = True
+                            break
+                        if rewind:
+                            break
+                        if (
+                            deadline is not None
+                            and loops > 0
+                            and _time.monotonic() >= deadline
+                        ):
+                            truncated = True
+                            done = True
+                            break
+                        cap = (
+                            hard_cap if deadline is None
+                            else min(loops + chunk, hard_cap)
+                        )
+                        t0 = _time.monotonic()
+                        _tledger.note_donated(
+                            (carry_w, ptr_w, fs, segc), site="pass1.window_chunk"
+                        )
+                        carry_w, ptr_w, fs, segc = _pass1_window_chunk(
+                            dev_w, carry_w, ptr_w, fs, segc, budgets,
+                            jnp.int32(cap), trunc,
+                        )
+                        _wait(carry_w.loops)
+                        executed = max(1, int(_host(carry_w.loops)) - loops)
+                        if deadline is not None:
+                            chunk = _adapt_chunk(t0, executed)
+                with tracing.span("solve.gather") as sp:
+                    gathers.append(sp)
+                    # scatter_back donates the full carry (in-place window
+                    # row writes — hot-window's whole point).
+                    _tledger.note_donated(carry, site="scatter_back")
+                    carry, ptr = scatter_back(
+                        carry, carry_w, ptr_w, sidx, jidx, win_base, Ws
                     )
-                    jax.block_until_ready(carry_w.loops)
-                    executed = max(1, int(np.asarray(carry_w.loops)) - loops)
-                    if deadline is not None:
-                        chunk = _adapt_chunk(t0, executed)
-                t0 = _time.monotonic()
-                # scatter_back donates the full carry (in-place window
-                # row writes — hot-window's whole point).
-                _tledger.note_donated(carry, site="scatter_back")
-                carry, ptr = scatter_back(
-                    carry, carry_w, ptr_w, sidx, jidx, win_base, Ws
-                )
-                gather_s += _time.monotonic() - t0
+                    if done:
+                        _wait(carry.loops)
                 if not done:
                     rewindows += 1
 
-        jax.block_until_ready(carry.loops)
-        pass1_s = _time.monotonic() - t_pass - gather_s
-        t0 = _time.monotonic()
-        out = _round_finish_jit(
-            dev, carry, budgets, fair_share, demand_capped, uncapped, truncated
-        )
-        jax.block_until_ready(out["num_loops"])
-        finish_s = _time.monotonic() - t0
-        seg_np = np.asarray(segc)
-        out, _expand = _materialize_out(out, dev, readback_rows)
-        _tledger.note_down(out, site="solve.d2h")
-        out = _expand(out)
-        # ARMADA_DEBUG_FINITE=1 debug net: name the first non-finite
-        # output array at the seam it left the device, before any
-        # downstream consumer can launder the NaN into a placement.
-        from .validate import maybe_assert_finite
+        with tracing.span("solve.finish") as finish:
+            out = _round_finish_jit(
+                dev, carry, budgets, fair_share, demand_capped, uncapped,
+                truncated,
+            )
+            _wait(out["num_loops"])
+            seg_np = _host(segc)
+        with tracing.span("solve.readback"):
+            out, _expand = _materialize_out(out, dev, readback_rows)
+            _tledger.note_down(out, site="solve.d2h")
+            out = _expand(out)
+            # ARMADA_DEBUG_FINITE=1 debug net: name the first non-finite
+            # output array at the seam it left the device, before any
+            # downstream consumer can launder the NaN into a placement.
+            from .validate import maybe_assert_finite
 
-        maybe_assert_finite(out, "kernel.solve_round[host-driven]")
-        if use_budget:
-            out["truncated"] = truncated
-        out["profile"] = {
-            "setup_s": round(setup_s, 4),
-            "pass1_s": round(pass1_s, 4),
-            "gather_s": round(gather_s, 4),
-            "finish_s": round(finish_s, 4),
+            maybe_assert_finite(out, "kernel.solve_round[host-driven]")
+        loop_mix = {
             "gang_loops": int(seg_np[SEG_GANG]),
             "fill_loops": int(seg_np[SEG_FILL]),
             "merged_fill_loops": int(seg_np[SEG_MERGED]),
+        }
+        solve_span.attrs.update(
+            host_syncs=syncs, rewindows=rewindows, pass1_runs=len(pass1),
+            gathers=len(gathers), **loop_mix,
+        )
+        if use_budget:
+            out["truncated"] = truncated
+        out["profile"] = {
+            "setup_s": round(setup.duration_s, 4),
+            "pass1_s": round(sum(s.duration_s for s in pass1), 4),
+            "gather_s": round(sum(s.duration_s for s in gathers), 4),
+            "finish_s": round(finish.duration_s, 4),
+            **loop_mix,
             "compacted": plan is not None,
             "window_slots": int(plan[0]) if plan else 0,
             "rewindows": rewindows,

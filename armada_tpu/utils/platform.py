@@ -1,7 +1,8 @@
 """JAX set-up shared by every entry point.
 
-`setup_jax` turns on the exact (x64) solver configuration and the
-persistent compile cache, then takes the platform JAX is configured
+`setup_jax` turns on the exact (x64) solver configuration, the
+persistent compile cache and the process's GC pause counter, then takes
+the platform JAX is configured
 for (`JAX_PLATFORMS`). It never switches platforms: a process asked
 for a TPU that finds none fails in JAX's own start-up, and a caller
 that needs a specific platform passes `require=` to fail loudly too.
@@ -90,8 +91,12 @@ def enable_exact_costs():
 
 
 def setup_jax(require: str | None = None) -> str:
-    """Exact costs + compile cache; returns the platform JAX runs on.
+    """Exact costs + compile cache + the garbage collector's pause
+    counter (utils/tracing.GC_PAUSES); returns the platform JAX runs on.
     With `require`, raises unless that is the platform."""
+    from .tracing import GC_PAUSES
+
+    GC_PAUSES.install()
     enable_exact_costs()
     enable_persistent_compile_cache()
     import jax

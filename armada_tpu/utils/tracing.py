@@ -13,12 +13,25 @@ services/grpc_api.py injects/extracts it on every unary RPC — so one
 trace id follows a job submit -> ingest -> round -> lease -> run-report
 (see docs/operations.md "Tracing a stuck job"). Export to Perfetto via
 OtlpJsonFileExporter + tools/trace2perfetto.py.
+
+Every span is also a `jax.profiler.TraceAnnotation` for its duration
+(once jax is imported), so a profiler trace holds the program's spans on
+the same host clock as the device's ops. Library code opens spans with
+the module-level `span()`, which nests under whatever span this thread
+has open. `GC_PAUSES` counts the garbage collector's pauses (installed
+by utils/platform.setup_jax).
 """
 
 from __future__ import annotations
 
+import bisect
+import collections
 import contextlib
+import gc
+import itertools
 import re
+import secrets
+import sys
 import threading
 import time
 from dataclasses import dataclass, field
@@ -84,9 +97,22 @@ def current_span_id() -> str:
     return stack[-1].span_id if stack else ""
 
 
+def _profiler_annotation(name: str):
+    """A `jax.profiler.TraceAnnotation(name)` for the caller to enter
+    while a profiler session records, else None (the inactive check is
+    all it costs then). jax is never imported here: a process that has
+    not imported it has no profiler session either."""
+    prof = sys.modules.get("jax.profiler")
+    cls = getattr(prof, "TraceAnnotation", None)
+    if cls is None or not cls.is_enabled():
+        return None
+    return cls(name)
+
+
 @dataclass
 class Span:
     name: str
+    # time.monotonic() at start and end.
     start: float
     attrs: dict = field(default_factory=dict)
     end: float | None = None
@@ -97,6 +123,10 @@ class Span:
     span_id: str = ""
     parent_id: str = ""
     trace_id: str = ""
+    # Finish order within its tracer, and the tracer that opened it (the
+    # module-level `span()` nests library spans into that tracer).
+    seq: int = field(default=0, repr=False, compare=False)
+    owner: Tracer | None = field(default=None, repr=False, compare=False)
 
     @property
     def duration_s(self) -> float:
@@ -173,9 +203,12 @@ class OtlpJsonFileExporter:
 
 
 class Tracer:
-    """Per-process tracer: span stack per thread, ring buffer of finished
-    spans, optional logger export, optional OTLP exporter (batched;
-    flushed every `export_every` finished spans or on flush())."""
+    """Per-process tracer: span stack per thread, a ring buffer of
+    finished spans per span name (`keep` each, so a flood of one name —
+    the gRPC spans of a busy server — never evicts another), optional
+    logger export, optional OTLP exporter (batched; flushed every
+    `export_every` finished spans or on flush()). Span names are the
+    program's fixed vocabulary, so the rings are bounded too."""
 
     def __init__(self, logger=None, keep: int = 1024, exporter=None,
                  export_every: int = 64, export_interval_s: float = 10.0,
@@ -197,10 +230,14 @@ class Tracer:
         self._export_warned = False
         self.export_failures = 0
         self._last_flush = time.monotonic()
-        self.finished: list[Span] = []
+        self._rings: dict[str, collections.deque] = {}
+        self._seq = itertools.count(1)
         self._pending: list[Span] = []
         self._local = threading.local()
-        self._lock = threading.Lock()
+        # Re-entrant: the GC hook finishes a `gc` span from inside
+        # whatever allocation triggered the collection, possibly while
+        # this thread already holds the lock.
+        self._lock = threading.RLock()
         if exporter is not None:
             import atexit
 
@@ -228,9 +265,9 @@ class Tracer:
         from the wire: when there is no local parent span, the new span
         joins that remote trace instead of opening a fresh one (the
         server-side half of context propagation). A local parent always
-        wins — nesting inside this process is already one trace."""
-        import secrets
-
+        wins — nesting inside this process is already one trace. While
+        a profiler session records, the span is also a
+        `jax.profiler.TraceAnnotation` of the same name."""
         stack = self._stack()
         parent = stack[-1] if stack else None
         trace_id = parent.trace_id if parent else ""
@@ -239,22 +276,18 @@ class Tracer:
             remote = parse_traceparent(remote_parent)
             if remote is not None:
                 trace_id, parent_id = remote
-        s = Span(
-            name=name,
-            start=time.monotonic(),
-            attrs=attrs,
-            parent=parent.name if parent else "",
-            start_unix_ns=time.time_ns(),
-            span_id=secrets.token_hex(8),
-            parent_id=parent_id,
-            # Root spans open a new trace; children inherit it.
-            trace_id=trace_id or secrets.token_hex(16),
-        )
+        s = self._new_span(name, time.monotonic(), attrs, parent, parent_id,
+                           trace_id)
         stack.append(s)
         _active_stack().append(s)
+        annotation = _profiler_annotation(name)
+        if annotation is not None:
+            annotation.__enter__()
         try:
             yield s
         finally:
+            if annotation is not None:
+                annotation.__exit__(None, None, None)
             s.end = time.monotonic()
             stack.pop()
             _active_stack().pop()
@@ -266,42 +299,41 @@ class Tracer:
                     **attrs,
                 ).debug("span finished")
 
-    def add_span(
-        self,
-        name: str,
-        *,
-        start_unix_ns: int,
-        duration_s: float,
-        parent: Span | None = None,
-        **attrs,
-    ) -> Span:
-        """Record an already-finished span post hoc (e.g. the solve
-        profile's setup/pass1/gather/finish segments, measured inside the
-        kernel driver and emitted as children of the round span after the
-        solve returns). Timestamps are the caller's; the span lands in
-        the ring buffer and export batch like any other."""
-        import secrets
-
-        now = time.monotonic()
-        s = Span(
+    def _new_span(self, name, start, attrs, parent, parent_id, trace_id):
+        return Span(
             name=name,
-            start=now - duration_s,
-            end=now,
+            start=start,
             attrs=attrs,
             parent=parent.name if parent else "",
-            start_unix_ns=int(start_unix_ns),
+            start_unix_ns=time.time_ns() - int((time.monotonic() - start) * 1e9),
             span_id=secrets.token_hex(8),
-            parent_id=parent.span_id if parent else "",
-            trace_id=parent.trace_id if parent else secrets.token_hex(16),
+            parent_id=parent_id,
+            # Root spans open a new trace; children inherit it.
+            trace_id=trace_id or secrets.token_hex(16),
+            owner=self,
         )
+
+    def _finish_measured(self, name: str, start: float, end: float,
+                         **attrs) -> None:
+        """A span whose start and end (time.monotonic()) were read as
+        the work began and ended, by code that cannot hold a context
+        manager open across it (the GC hook); a child of this thread's
+        innermost open span."""
+        active = _active_stack()
+        parent = active[-1] if active else None
+        s = self._new_span(name, start, attrs, parent,
+                           parent.span_id if parent else "",
+                           parent.trace_id if parent else "")
+        s.end = end
         self._finish(s)
-        return s
 
     def _finish(self, s: Span) -> None:
         with self._lock:
-            self.finished.append(s)
-            if len(self.finished) > self.keep:
-                del self.finished[: len(self.finished) - self.keep]
+            s.seq = next(self._seq)
+            ring = self._rings.get(s.name)
+            if ring is None:
+                ring = self._rings[s.name] = collections.deque(maxlen=self.keep)
+            ring.append(s)
             flush_now = False
             if self.exporter is not None:
                 self._pending.append(s)
@@ -343,12 +375,26 @@ class Tracer:
                     self.max_pending,
                 )
 
+    def spans(self, name: str | None = None) -> list[Span]:
+        """The retained finished spans named `name` (of every name when
+        None), in the order they finished. Each has `start` and `end` on
+        time.monotonic(), `span_id`, `parent_id` and `attrs`."""
+        with self._lock:
+            if name is not None:
+                return list(self._rings.get(name, ()))
+            spans = [s for ring in self._rings.values() for s in ring]
+        spans.sort(key=lambda s: s.seq)
+        return spans
+
+    @property
+    def finished(self) -> list[Span]:
+        """Every retained finished span, in the order they finished."""
+        return self.spans()
+
     def summary(self) -> dict:
         """Aggregate durations by span name (count, total, max)."""
-        with self._lock:
-            spans = list(self.finished)
         out: dict[str, dict] = {}
-        for s in spans:
+        for s in self.spans():
             bucket = out.setdefault(
                 s.name, {"count": 0, "total_s": 0.0, "max_s": 0.0}
             )
@@ -361,26 +407,111 @@ class Tracer:
 # Process-wide default tracer (observability.Init analogue).
 TRACER = Tracer()
 
-# The solve profile's segment order (solver/kernel.solve_round's
-# `profile` block keys, minus the `_s` suffix).
-SOLVE_SEGMENTS = ("setup", "pass1", "gather", "finish")
+
+def span(name: str, **attrs):
+    """A span for library code that holds no tracer: a child of this
+    thread's innermost open span, in the tracer that opened it (so the
+    solve's spans nest under `scheduler.round` in whichever tracer the
+    scheduler has attached), else a root span in `TRACER`."""
+    active = _active_stack()
+    tracer = active[-1].owner if active else None
+    return (tracer or TRACER).span(name, **attrs)
 
 
-def add_segment_spans(tracer: Tracer, parent, start_unix_ns: int,
-                      profile: dict, prefix: str = "solve",
-                      segments=SOLVE_SEGMENTS, **attrs) -> int:
-    """Sequential child spans from a `{seg}_s` duration dict: each
-    segment starts where the previous ended. Shared by the scheduler's
-    round spans and bench's warm-cycle spans so the two Perfetto
-    timelines cannot drift. Returns the ns cursor after the last
-    segment."""
-    at = int(start_unix_ns)
-    for seg in segments:
-        dur = float(profile.get(f"{seg}_s", 0.0))
-        tracer.add_span(f"{prefix}.{seg}", start_unix_ns=at,
-                        duration_s=dur, parent=parent, **attrs)
-        at += int(dur * 1e9)
-    return at
+class GcPauses:
+    """Process counter of the garbage collector's pauses, fed by a
+    `gc.callbacks` hook: seconds, collections and objects collected per
+    generation, plus a timeline coarse enough to stay small (the running
+    total at the end of each `grain_s` of wall time, the last `keep` of
+    them) so a reader can ask for the pauses inside a window after the
+    fact. Each generation-2 collection is also a `gc` span in `tracer`;
+    while a profiler session records, every collection is a `gc`
+    annotation in its trace. The hook allocates a few floats per
+    collection (generation 0 runs every few hundred allocations)."""
+
+    def __init__(self, tracer: Tracer, grain_s: float = 0.1, keep: int = 8192):
+        self.tracer = tracer
+        self.seconds = [0.0, 0.0, 0.0]
+        self.count = [0, 0, 0]
+        self.collected = [0, 0, 0]
+        self.installed = False
+        self._grain = grain_s
+        self._keep = keep
+        self._mark_grain = [0] * keep
+        self._mark_total = [0.0] * keep
+        self._marks = 0
+        self._total = 0.0
+        self._t0 = 0.0
+        self._annotation = None
+
+    def install(self) -> None:
+        """Hook the collector (idempotent)."""
+        if not self.installed:
+            gc.callbacks.append(self._callback)
+            self.installed = True
+
+    def _callback(self, phase, info):
+        if phase == "start":
+            self._annotation = _profiler_annotation("gc")
+            if self._annotation is not None:
+                self._annotation.__enter__()
+            self._t0 = time.monotonic()
+            return
+        t1 = time.monotonic()
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
+        self._note(info["generation"], info["collected"], self._t0, t1)
+
+    def _note(self, generation: int, collected: int, t0: float, t1: float):
+        """Count one collection that paused from t0 to t1."""
+        pause = t1 - t0
+        gen = min(int(generation), 2)
+        self.seconds[gen] += pause
+        self.count[gen] += 1
+        self.collected[gen] += collected
+        self._total += pause
+        g = int(t1 / self._grain)
+        last = (self._marks - 1) % self._keep
+        if self._marks and self._mark_grain[last] == g:
+            self._mark_total[last] = self._total
+        else:
+            at = self._marks % self._keep
+            self._mark_grain[at] = g
+            self._mark_total[at] = self._total
+            self._marks += 1
+        if gen == 2:
+            self.tracer._finish_measured(
+                "gc", t0, t1, generation=gen, collected=collected
+            )
+
+    def _total_at(self, t: float) -> float | None:
+        """Pause seconds of the collections that ended by the end of
+        `t`'s grain; None when the timeline no longer reaches back."""
+        n = min(self._marks, self._keep)
+        start = self._marks - n
+        order = [(start + i) % self._keep for i in range(n)]
+        grains = [self._mark_grain[i] for i in order]
+        g = int(t / self._grain)
+        k = bisect.bisect_right(grains, g)
+        if k == 0:
+            return 0.0 if self._marks <= self._keep else None
+        return self._mark_total[order[k - 1]]
+
+    def pause_s_between(self, t0: float, t1: float) -> float | None:
+        """Seconds the collector paused between time.monotonic() `t0`
+        and `t1`, to the grain at each end; None before the hook was
+        installed or where the timeline no longer reaches back to t0."""
+        if not self.installed:
+            return None
+        lo, hi = self._total_at(t0), self._total_at(t1)
+        if lo is None or hi is None:
+            return None
+        return hi - lo
+
+
+# The process's GC counter; utils/platform.setup_jax installs it.
+GC_PAUSES = GcPauses(TRACER)
 
 
 @contextlib.contextmanager

@@ -45,9 +45,7 @@ this host's target signature (hot window + budgeted chunk stride) to
 every config — the A/B against the static defaults is just the same
 bench run with and without the variable; the effective (possibly tuned)
 parameters are always recorded under extra.params so artifacts are
-self-describing either way; BENCH_SPANS=<path> exports every measured
-warm cycle's phase spans as OTLP-JSON lines (tools/trace2perfetto.py
-renders the run in Perfetto).
+self-describing either way.
 
 The LAST stdout line is always one JSON object with an "ok" flag — on
 any failure it carries ok=false and the error instead of silently dying
@@ -201,47 +199,8 @@ def _put(dev):
     return out
 
 
-def _emit_cycle_spans(tracer, config_name, timings, profile):
-    """One warm cycle -> a span tree with the measured phase durations
-    (delta apply / device prep / h2d / solve, plus the solve profile's
-    segments when the host-driven driver ran)."""
-    end_ns = time.time_ns()
-    cycle_s = timings["cycle_s"]
-    start_ns = end_ns - int(cycle_s * 1e9)
-    transfer = timings.get("transfer") or {}
-    compiles = transfer.get("compiles") or {}
-    parent = tracer.add_span(
-        "bench.warm_cycle",
-        start_unix_ns=start_ns,
-        duration_s=cycle_s,
-        config=config_name,
-        scheduled_jobs=timings["scheduled_jobs"],
-        loops=timings["loops"],
-        # The cost ledger on the cycle span: the Perfetto view answers
-        # "churn or solve" without leaving the timeline.
-        transfer_bytes_up=int(transfer.get("bytes_up", 0)),
-        transfer_bytes_down=int(transfer.get("bytes_down", 0)),
-        transfer_donated_buffers=int(transfer.get("donated_buffers", 0)),
-        xla_compiles=int(compiles.get("compiles", 0)),
-    )
-    from armada_tpu.utils.tracing import add_segment_spans
-
-    at = start_ns
-    for phase in ("delta_s", "prep_s", "h2d_s", "solve_s"):
-        dur = float(timings[phase])
-        tracer.add_span(
-            f"bench.{phase[:-2]}",
-            start_unix_ns=at,
-            duration_s=dur,
-            parent=parent,
-        )
-        if phase == "solve_s" and profile:
-            add_segment_spans(tracer, parent, at, profile)
-        at += int(dur * 1e9)
-
-
 def run_config(n_jobs, n_nodes, burst=None, mesh=None, fill_window=None,
-               hot_window=None, trace_path=None, span_tracer=None):
+               hot_window=None, trace_path=None):
     """Cold build, one shape-settling warm cycle, then >=5 measured warm
     cycles (BENCH_WARM_CYCLES): the headline is the MEDIAN cycle with its
     spread (min/max + IQR), not a single sample — a single warm cycle can
@@ -439,14 +398,6 @@ def run_config(n_jobs, n_nodes, burst=None, mesh=None, fill_window=None,
             # Per-segment solve profile (setup / pass-1 / gather /
             # finish wall clock + loop mix) from the host-driven driver.
             timings["segments"] = out["profile"]
-        if span_tracer is not None:
-            # BENCH_SPANS: the cycle and its component phases as
-            # post-hoc spans — tools/trace2perfetto.py renders the whole
-            # bench run as a Perfetto timeline.
-            _emit_cycle_spans(
-                span_tracer, f"{n_jobs}x{n_nodes}", timings,
-                out.get("profile"),
-            )
         return timings, out
 
     first, out = warm_cycle(out)  # may pay a shape-change compile once
@@ -684,21 +635,6 @@ def _run_matrix(partial=None):
         trace_path = os.path.join(
             os.path.dirname(os.path.abspath(__file__)), "BENCH_trace.atrace"
         )
-    # BENCH_SPANS=<path>: export every measured warm cycle's phase spans
-    # as OTLP-JSON lines; tools/trace2perfetto.py converts the file into
-    # a Perfetto-loadable timeline of the bench run.
-    span_tracer = None
-    spans_path = os.environ.get("BENCH_SPANS") or None
-    if spans_path:
-        from armada_tpu.utils.tracing import OtlpJsonFileExporter, Tracer
-
-        open(spans_path, "w").close()  # one bench run = one span file
-        span_tracer = Tracer(
-            exporter=OtlpJsonFileExporter(
-                spans_path, service_name="armada-tpu-bench"
-            ),
-            export_every=256,
-        )
     tracking = burst50k = None
     if custom:
         n_jobs = int(os.environ.get("BENCH_JOBS", 100_000))
@@ -708,7 +644,7 @@ def _run_matrix(partial=None):
         # autotune A/B's forced-rewindow scenario).
         burst = int(os.environ.get("BENCH_BURST", 0) or 0) or None
         flag = run_config(n_jobs, n_nodes, burst=burst, mesh=mesh,
-                          trace_path=trace_path, span_tracer=span_tracer)
+                          trace_path=trace_path)
     else:
         n_jobs, n_nodes = 1_000_000, 50_000
         # Like-for-like vs earlier rounds: the historical 512 fill
@@ -716,25 +652,17 @@ def _run_matrix(partial=None):
         # amortize the host-driven driver's fixed overhead).
         tracking = run_config(
             100_000, 5000, mesh=mesh, fill_window=512, hot_window=0,
-            span_tracer=span_tracer,
         )
         partial["tracking_100k"] = tracking
         if os.environ.get("BENCH_FLAGSHIP", "1") == "1":
-            flag = run_config(n_jobs, n_nodes, mesh=mesh, trace_path=trace_path,
-                              span_tracer=span_tracer)
+            flag = run_config(n_jobs, n_nodes, mesh=mesh, trace_path=trace_path)
             partial["flagship"] = flag
             if os.environ.get("BENCH_BURST50K", "1") == "1":
-                burst50k = run_config(
-                    n_jobs, n_nodes, burst=50_000, mesh=mesh,
-                    span_tracer=span_tracer,
-                )
+                burst50k = run_config(n_jobs, n_nodes, burst=50_000, mesh=mesh)
                 partial["burst_50k"] = burst50k
         else:
             flag, (n_jobs, n_nodes) = tracking, (100_000, 5000)
             tracking = None
-    if span_tracer is not None:
-        span_tracer.flush()
-
     extra = dict(flag)
     cycle_s = extra.pop("cycle_s")
     extra["platform"] = platform

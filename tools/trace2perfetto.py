@@ -5,8 +5,9 @@ Two input kinds, one output: the Chrome trace-event JSON format
 that ui.perfetto.dev and chrome://tracing load directly.
 
   - OTLP-JSON span files (`utils/tracing.OtlpJsonFileExporter` output:
-    one resourceSpans batch per line) — scheduler cycle/round/solve
-    spans, bench warm-cycle spans (BENCH_SPANS=...), simulator runs
+    one resourceSpans batch per line) — scheduler cycle/round spans
+    with the round's measured stages (round.*, residency.*, solve.*),
+    from a live scheduler's attached tracer or a simulator run
     (Simulator(span_path=...)). Spans become complete ("X") events,
     one track per trace id, so a whole run's rounds and their
     setup/pass1/gather/finish segments render as a timeline.
@@ -19,7 +20,7 @@ that ui.perfetto.dev and chrome://tracing load directly.
 
 Usage:
   python tools/trace2perfetto.py run.otlp.jsonl -o run.perfetto.json
-  python tools/trace2perfetto.py sim.atrace bench.otlp.jsonl -o all.json
+  python tools/trace2perfetto.py sim.atrace run.otlp.jsonl -o all.json
   python tools/trace2perfetto.py --check        # fixture round-trip gate
 
 --check converts the committed tests/fixtures/sim_steady.atrace and
