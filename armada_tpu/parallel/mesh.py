@@ -166,7 +166,11 @@ def sharded_solve(mesh: Mesh, dist, specs: dict):
         # so the stats describe THIS compiled program only.
         if dist.stats is not None:
             dist.stats.begin_trace()
-        return solve_impl(dev, dist=dist)
+        out = solve_impl(dev, dist=dist)
+        # The compaction path count (solver/segments.py) is a span
+        # attribute of the single-device driver, not a round output.
+        del out["compactions"]
+        return out
 
     def build(dev: DeviceRound):
         return jax.jit(

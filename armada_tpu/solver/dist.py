@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.select import lex_argmin, lexsort, masked_keys
+from .segments import no_compaction, scatter_rows
 
 
 @dataclasses.dataclass
@@ -167,6 +168,24 @@ class LocalDist:
             contrib, jnp.clip(nodes, 0, ln - 1), num_segments=ln
         )
 
+    def sparse_segment_to_nodes(self, contrib, nodes, ln):
+        """segment_to_nodes over a whole job axis of which few rows are
+        nonzero: only those rows are scattered (solver/segments.py).
+        Returns (sums, int32[2] compaction path count)."""
+        live = jnp.any(contrib != 0, axis=tuple(range(1, contrib.ndim)))
+
+        def bounded(idx):
+            ok = idx >= 0
+            rows = jnp.clip(idx, 0, contrib.shape[0] - 1)
+            okb = ok.reshape(ok.shape + (1,) * (contrib.ndim - 1))
+            return self.segment_to_nodes(
+                jnp.where(okb, contrib[rows], 0), nodes[rows], ln
+            )
+
+        return scatter_rows(
+            live, bounded, lambda: self.segment_to_nodes(contrib, nodes, ln)
+        )
+
     def fill_candidates(self, keys, mask, caps, gids, B, path="lax", nbits=None):
         """The globally best (lex-smallest-key) <=B candidate nodes, in fill
         order: (caps[B'], gids[B']) with caps 0 for masked-out entries. A
@@ -262,6 +281,10 @@ class ShardDist:
             local,
             num_segments=ln,
         )
+
+    def sparse_segment_to_nodes(self, contrib, nodes, ln):
+        """The shard's own segment_to_nodes over the whole job axis."""
+        return self.segment_to_nodes(contrib, nodes, ln), no_compaction()
 
     def fill_candidates(self, keys, mask, caps, gids, B, path="lax", nbits=None):
         """Per-shard top-B by local sort, then an all_gather of the K*B

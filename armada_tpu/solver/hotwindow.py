@@ -55,6 +55,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from .segments import compact
+
 NO_NODE = -1
 
 # Fill values making a dead (index -1) window row inert for every kernel
@@ -145,8 +147,9 @@ def gather_window(dev, carry, ptr, Ws: int, Ep: int):
         & (s_j < ptr[jq] + win_len[jq])
     )
     ev_mask = (carry.evict_rank >= 0) & ~in_win
-    (ev_idx,) = jnp.nonzero(ev_mask, size=Ep, fill_value=-1)
-    jidx = jnp.concatenate([mem.reshape(-1), ev_idx.astype(jnp.int32)])
+    # jnp.nonzero(ev_mask, size=Ep, fill_value=-1) without its scatter.
+    ev_idx, _ = compact(ev_mask, Ep)
+    jidx = jnp.concatenate([mem.reshape(-1), ev_idx])
 
     pos = jnp.arange(Q * Ws, dtype=jnp.int32)
     members_w = jnp.where(

@@ -45,6 +45,9 @@ from ..ops.select import lex_argmin, lexsort, masked_lexsort
 from ..utils import tracing
 from .dist import LOCAL
 from .kernel_prep import DeviceRound, _pow2
+from .segments import (
+    no_compaction, scatter_rows, segment_min_dense, segment_sum_dense,
+)
 
 # Segment-counter indices: pass-1 loop kinds for the solve profile
 # (serial gang attempts, single-queue batched fill, merged multi-queue
@@ -784,7 +787,7 @@ def _queue_heads(dev, valid):
     Q = dev.queue_slot_start.shape[0]
     pos = jnp.where(valid, jnp.arange(S, dtype=jnp.int32), BIG)
     seg = jnp.clip(dev.slot_queue, 0, Q - 1)
-    heads = jax.ops.segment_min(pos, seg, num_segments=Q)
+    heads = segment_min_dense(pos, seg, Q)
     return jnp.where(heads < BIG, heads, 0).astype(jnp.int32), heads < BIG
 
 
@@ -1774,11 +1777,15 @@ def _schedule_pass(
 
 def _apply_evictions(dev, dist, carry: Carry, evict_mask):
     """Move evicted jobs' usage to the evicted row and update queue
-    accounting (EvictJobsFromNode + sctx.EvictJob)."""
+    accounting (EvictJobsFromNode + sctx.EvictJob). Returns (carry,
+    compaction path count, solver/segments.py)."""
     P = dev.priorities.shape[0]
+    Q = dev.queue_weight.shape[0]
+    C = dev.pc_priority.shape[0]
     req = dev.job_req
     alloc = carry.alloc
     ln = alloc.shape[1]
+    paths = no_compaction()
     for r in range(1, P):
         in_rows = jnp.where(
             dev.job_preemptible,
@@ -1788,24 +1795,15 @@ def _apply_evictions(dev, dist, carry: Carry, evict_mask):
         contrib = jnp.where(
             (evict_mask & in_rows)[:, None], dev.job_req_fit, 0
         ).astype(alloc.dtype)
-        add = dist.segment_to_nodes(contrib, carry.job_node, ln)
+        add, path = dist.sparse_segment_to_nodes(contrib, carry.job_node, ln)
         alloc = alloc.at[r].add(add)
+        paths = paths + path
 
-    qseg = jnp.clip(dev.job_queue, 0, dev.queue_weight.shape[0] - 1)
-    qsub = jax.ops.segment_sum(
-        jnp.where(evict_mask[:, None], _f(req), 0.0),
-        qseg,
-        num_segments=dev.queue_weight.shape[0],
-    )
-    qalloc = carry.qalloc - qsub
+    qseg = jnp.clip(dev.job_queue, 0, Q - 1)
+    evicted_req = jnp.where(evict_mask[:, None], _f(req), 0.0)
+    qsub = segment_sum_dense(evicted_req, qseg, Q)
     # per-PC accounting
-    C = dev.pc_priority.shape[0]
-    pc_seg = qseg * C + dev.job_pc
-    qpc_sub = jax.ops.segment_sum(
-        jnp.where(evict_mask[:, None], _f(req), 0.0),
-        pc_seg,
-        num_segments=dev.queue_weight.shape[0] * C,
-    ).reshape(carry.qpc_alloc.shape)
+    qpc_sub = segment_sum_dense(evicted_req, qseg * C + dev.job_pc, Q * C)
     floating_sub = jnp.sum(
         jnp.where(
             (evict_mask[:, None] & dev.floating_mask[None, :]), _f(req), 0.0
@@ -1814,11 +1812,11 @@ def _apply_evictions(dev, dist, carry: Carry, evict_mask):
     )
     return carry._replace(
         alloc=alloc,
-        qalloc=qalloc,
-        qpc_alloc=carry.qpc_alloc - qpc_sub,
+        qalloc=carry.qalloc - qsub,
+        qpc_alloc=carry.qpc_alloc - qpc_sub.reshape(carry.qpc_alloc.shape),
         floating=carry.floating - floating_sub,
         job_evicted=carry.job_evicted | evict_mask,
-    )
+    ), paths
 
 
 def _assign_evict_ranks(dev, carry: Carry, budgets, prefer_large: bool):
@@ -1903,18 +1901,29 @@ def _oversubscribed_mask(dev, dist, carry: Carry):
 
 
 def _gang_complete_mask(dev, carry: Carry, evict_mask):
-    """Extend an eviction mask to whole gangs (evictGangs)."""
+    """Extend an eviction mask to whole gangs (evictGangs). Returns
+    (mask, compaction path count, solver/segments.py)."""
     S, M = dev.slot_members.shape
-    safe = jnp.clip(dev.slot_members, 0, evict_mask.shape[0] - 1)
+    if M == 1:
+        # No slot holds two members, so no gang has one to add.
+        return evict_mask, no_compaction()
+    J = evict_mask.shape[0]
+    safe = jnp.clip(dev.slot_members, 0, J - 1)
     member_mask = jnp.arange(M)[None, :] < dev.slot_count[:, None]
     slot_has_evicted = jnp.any(member_mask & evict_mask[safe], axis=1)
     bound = (carry.job_node >= 0) & ~carry.job_evicted
-    add = jnp.zeros_like(evict_mask)
     slot_sel = slot_has_evicted & (dev.slot_count > 1)
     flat = safe.reshape(-1)
     sel_flat = (slot_sel[:, None] & member_mask).reshape(-1)
-    add = add.at[flat].max(sel_flat)
-    return evict_mask | (add & bound)
+
+    def bounded(idx):
+        jobs = jnp.where(idx >= 0, flat[jnp.clip(idx, 0, flat.shape[0] - 1)], J)
+        return jnp.zeros_like(evict_mask).at[jobs].set(True, mode="drop")
+
+    add, path = scatter_rows(
+        sel_flat, bounded, lambda: jnp.zeros_like(evict_mask).at[flat].max(sel_flat)
+    )
+    return evict_mask | (add & bound), path
 
 
 def _round_setup(dev: DeviceRound, dist=LOCAL):
@@ -1975,14 +1984,14 @@ def _round_setup(dev: DeviceRound, dist=LOCAL):
     )
     # Initial per-PC allocation of running jobs.
     qseg = jnp.clip(dev.job_queue, 0, Q - 1) * C + dev.job_pc
-    run_alloc = jax.ops.segment_sum(
+    run_alloc = segment_sum_dense(
         jnp.where(
             (dev.job_is_running & (dev.job_queue >= 0))[:, None],
             _f(dev.job_req),
             0.0,
         ),
         qseg,
-        num_segments=Q * C,
+        Q * C,
     ).reshape(Q, C, R)
     carry = carry._replace(qpc_alloc=run_alloc)
 
@@ -2006,29 +2015,33 @@ def _round_setup(dev: DeviceRound, dist=LOCAL):
             & (carry.job_node >= 0)
             & evict_queue[qidx]
         )
-    evict0 = _gang_complete_mask(dev, carry, evict0)
-    carry = _apply_evictions(dev, dist, carry, evict0)
+    evict0, gang_paths = _gang_complete_mask(dev, carry, evict0)
+    carry, evict_paths = _apply_evictions(dev, dist, carry, evict0)
     carry = _assign_evict_ranks(dev, carry, budgets, dev.prefer_large)
-    return carry, budgets, fair_share, demand_capped, uncapped
+    compactions = gang_paths + evict_paths
+    return carry, budgets, fair_share, demand_capped, uncapped, compactions
 
 
 def _round_finish(
-    dev: DeviceRound, dist, carry, budgets, fair_share, demand_capped, uncapped
+    dev: DeviceRound, dist, carry, budgets, fair_share, demand_capped, uncapped,
+    compactions,
 ):
     """Steps 3-5 after pass 1: oversubscription eviction, pass 2 and
-    finalization into the result dict."""
+    finalization into the result dict. `compactions` is the setup's
+    path count (solver/segments.py); the dict's `compactions` adds this
+    program's."""
     J = dev.job_req.shape[0]
     Q = dev.queue_weight.shape[0]
 
     # 3. Oversubscription eviction.
     over = _oversubscribed_mask(dev, dist, carry)
-    over = _gang_complete_mask(dev, carry, over)
+    over, gang_paths = _gang_complete_mask(dev, carry, over)
     # Back out per-round scheduled resources for re-evicted new jobs.
     sched_backout = jnp.sum(
         jnp.where((over & carry.job_scheduled)[:, None], _f(dev.job_req), 0.0),
         axis=0,
     )
-    carry = _apply_evictions(dev, dist, carry, over)
+    carry, evict_paths = _apply_evictions(dev, dist, carry, over)
     carry = carry._replace(scheduled_new=carry.scheduled_new - sched_backout)
     # Re-open ONLY slots whose members were just oversubscription-evicted
     # (pass 2 considers the fresh eviction set, not pass-1 leftovers).
@@ -2082,11 +2095,14 @@ def _round_finish(
         "uncapped_fair_share": uncapped,
         "num_loops": carry.loops,
         "spot_price": carry.spot_price,
+        "compactions": compactions + gang_paths + evict_paths,
     }
 
 
 def solve_impl(dev: DeviceRound, dist=LOCAL):
-    carry, budgets, fair_share, demand_capped, uncapped = _round_setup(dev, dist)
+    carry, budgets, fair_share, demand_capped, uncapped, compactions = (
+        _round_setup(dev, dist)
+    )
 
     # 2. Pass 1: evicted + queued.
     carry = _schedule_pass(
@@ -2100,7 +2116,8 @@ def solve_impl(dev: DeviceRound, dist=LOCAL):
         prefer_large=dev.prefer_large,
     )
     return _round_finish(
-        dev, dist, carry, budgets, fair_share, demand_capped, uncapped
+        dev, dist, carry, budgets, fair_share, demand_capped, uncapped,
+        compactions,
     )
 
 
@@ -2120,12 +2137,14 @@ _solve = jax.jit(solve_impl)
 
 
 def _pass1_begin_impl(dev: DeviceRound):
-    carry, budgets, fair_share, demand_capped, uncapped = _round_setup(dev)
+    carry, budgets, fair_share, demand_capped, uncapped, compactions = (
+        _round_setup(dev)
+    )
     ptr0 = _pass_init_ptrs(dev, carry, True, True)
     carry = carry._replace(
         stop=jnp.zeros((), bool), loops=jnp.zeros((), jnp.int32)
     )
-    return carry, ptr0, budgets, fair_share, demand_capped, uncapped
+    return carry, ptr0, budgets, fair_share, demand_capped, uncapped, compactions
 
 
 def _pass1_chunk_impl(dev: DeviceRound, carry, ptr, fs, segc, budgets, loop_cap):
@@ -2162,9 +2181,7 @@ def _normalize_window_ptrs(dev, carry, ptr, include_queued, use_key_skip):
     pos = jnp.arange(S, dtype=jnp.int32)
     seg = jnp.clip(dev.slot_queue, 0, Q - 1)
     ahead = valid & (pos >= ptr[seg])
-    heads = jax.ops.segment_min(
-        jnp.where(ahead, pos, BIG), seg, num_segments=Q
-    )
+    heads = segment_min_dense(jnp.where(ahead, pos, BIG), seg, Q)
     return jnp.where(heads < BIG, heads, dev.queue_slot_end).astype(jnp.int32)
 
 
@@ -2203,7 +2220,7 @@ def _pass1_window_chunk_impl(
 
 
 def _finish_impl(dev: DeviceRound, carry, budgets, fair_share, demand_capped,
-                 uncapped, rescue: bool):
+                 uncapped, compactions, rescue: bool):
     # Rescue pass for truncated rounds: pass 1 evicts running jobs up
     # front, so stopping it early would finalize evicted-but-never-
     # attempted jobs as PREEMPTED — mass preemption, not degradation. An
@@ -2230,7 +2247,8 @@ def _finish_impl(dev: DeviceRound, carry, budgets, fair_share, demand_capped,
         )
         carry = carry._replace(loops=loops0 + carry.loops)
     return _round_finish(
-        dev, LOCAL, carry, budgets, fair_share, demand_capped, uncapped
+        dev, LOCAL, carry, budgets, fair_share, demand_capped, uncapped,
+        compactions,
     )
 
 
@@ -2243,7 +2261,7 @@ _pass1_norm = jax.jit(_pass1_norm_impl, donate_argnums=(2,))
 _pass1_window_chunk = jax.jit(
     _pass1_window_chunk_impl, donate_argnums=(1, 2, 3, 4)
 )
-_round_finish_jit = jax.jit(_finish_impl, static_argnums=(6,))
+_round_finish_jit = jax.jit(_finish_impl, static_argnums=(7,))
 
 
 def _window_precheck(dev: DeviceRound, window, min_slots):
@@ -2357,6 +2375,15 @@ def _materialize_out(out, dev, readback_rows):
     return np_out, expand
 
 
+def _note_compactions(span, out):
+    """Move the solve's compaction path count (solver/segments.py) from
+    its outputs onto its `solve` span: `compact_bounded` and
+    `compact_fallback`, how many full-axis scatters ran on the
+    compacted rows and how many on the whole axis."""
+    bounded, fallback = (int(x) for x in out.pop("compactions"))
+    span.attrs.update(compact_bounded=bounded, compact_fallback=fallback)
+
+
 def solve_round(
     dev: DeviceRound,
     *,
@@ -2404,7 +2431,9 @@ def solve_round(
     one. The profile's `*_s` values are those spans' durations. The
     `solve` span carries `host_syncs` (every block_until_ready or
     np.asarray the driver makes before the readback), the rewindows and
-    the loop counts.
+    the loop counts on the host-driven path, and on both paths
+    `compact_bounded` / `compact_fallback` (solver/segments.py), read
+    in the readback.
 
     readback_rows (the unpadded live-job count) trims the device->host
     readback of the per-job decision arrays to that prefix — the padded
@@ -2433,13 +2462,14 @@ def solve_round(
         # outputs into whatever round ledger the caller activated.
         from .validate import maybe_assert_finite
 
-        with tracing.span("solve", path="fused", host_syncs=1):
+        with tracing.span("solve", path="fused", host_syncs=1) as solve_span:
             with tracing.span("solve.fused"):
                 _tledger.note_up(dev, site="solve.dispatch")
                 out = _solve(dev)
                 jax.block_until_ready(out)
             with tracing.span("solve.readback"):
                 out, _expand = _materialize_out(out, dev, readback_rows)
+                _note_compactions(solve_span, out)
                 _tledger.note_down(out, site="solve.d2h")
                 out = _expand(out)
                 maybe_assert_finite(out, "kernel.solve_round[fused]")
@@ -2471,9 +2501,8 @@ def solve_round(
             # instead of re-transferring the host arrays per segment.
             _tledger.note_up(dev, site="solve.h2d")
             dev = jax.device_put(dev)
-            carry, ptr, budgets, fair_share, demand_capped, uncapped = (
-                _pass1_begin(dev)
-            )
+            (carry, ptr, budgets, fair_share, demand_capped, uncapped,
+             compactions) = _pass1_begin(dev)
             _wait(carry.loops)
             plan = _window_plan(dev, carry, pre)
             syncs += pre is not None  # its evicted-count readback
@@ -2602,12 +2631,13 @@ def solve_round(
         with tracing.span("solve.finish") as finish:
             out = _round_finish_jit(
                 dev, carry, budgets, fair_share, demand_capped, uncapped,
-                truncated,
+                compactions, truncated,
             )
             _wait(out["num_loops"])
             seg_np = _host(segc)
         with tracing.span("solve.readback"):
             out, _expand = _materialize_out(out, dev, readback_rows)
+            _note_compactions(solve_span, out)
             _tledger.note_down(out, site="solve.d2h")
             out = _expand(out)
             # ARMADA_DEBUG_FINITE=1 debug net: name the first non-finite
