@@ -2,22 +2,25 @@
 
 The timed path is the program's own warm cycle, as the scheduler service
 runs it for one pool: `IncrementalRound` deltas (bind last round's
-leases, remove completed jobs, add new submissions), the device-resident
-sync (`ResidentRound.device_round`), and `solver/kernel.solve_round` with
-the configuration's hot-window settings and the program's default kernel
-path, read back to the host. Nothing is chosen for the program that the
-scheduler service would not choose.
+leases, end the jobs it preempted, remove completed jobs, add new
+submissions), the device-resident sync (`ResidentRound.device_round`),
+and `solver/kernel.solve_round` with the configuration's hot-window
+settings and the program's default kernel path, read back to the host.
+Nothing is chosen for the program that the scheduler service would not
+choose.
 
 Set-up builds the deployment from the seed, uploads it, and runs
 `warmup_rounds` full rounds so every program the window calls is
-compiled or loaded from the persistent cache. The window then runs
-rounds back to back for `--seconds`; `cycle_s` is the window's time over
-its rounds; a window that compiles anything is not correct. After the
-window, the last round's decisions are compared with the plain reference
-on a round that the reference's own builder
-(`benchmark/reference/inputs.py`) makes from the benchmark's record of
-the jobs, so the deltas, the snapshot and the residency sync are checked
-along with the solve.
+compiled or loaded from the persistent cache. Where a warm-up round
+preempts, delta sizes vary from round to round, and set-up then also
+compiles every delta program of the residency sync
+(`harness.warm_resident`). The window then runs rounds back to back for
+`--seconds`; `cycle_s` is the window's time over its rounds; a window
+that compiles anything is not correct. After the window, the last
+round's decisions are compared with the plain reference on a round that
+the reference's own input code (`benchmark/reference/inputs.py`) makes
+from the benchmark's record of the jobs, so the deltas, the snapshot and
+the residency sync are checked along with the solve.
 """
 
 from __future__ import annotations
@@ -35,7 +38,8 @@ def run(run):
 
     from benchmark import check, generate
     from benchmark.harness import (
-        T_START, check_window_compiles, json_line, log_setup, window_programs,
+        T_START, check_window_compiles, json_line, log_setup, warm_resident,
+        window_programs,
     )
 
     cell = run.cell
@@ -58,16 +62,20 @@ def run(run):
     min_slots = cfg.hot_window_min_slots
     period = float(config["schedule_period_s"])
 
-    def one_round(k, leases):
-        """Apply last round's leases, complete as many running jobs,
-        submit as many new ones, sync, solve; returns the next leases."""
+    def one_round(k, leases, preempted):
+        """Apply last round's decisions as the scheduler service does:
+        its leases bind, and the jobs it preempted end (a round's
+        preemption is terminal for the job, jobdb/ingest.py). Complete as
+        many running jobs as were leased, submit as many new ones, sync,
+        solve; returns the round's output, leases and preempted jobs."""
         with run.span("round"):
             with run.span("deltas"):
                 n = len(leases)
+                state.end(preempted)
                 done = state.complete(n, rng)
                 fresh = backlog.take(n)
                 inc.bind(leases)
-                inc.remove_jobs(done)
+                inc.remove_jobs(preempted + done)
                 inc.add_jobs(fresh)
                 state.apply(leases, done, fresh)
             with run.span("sync"):
@@ -78,14 +86,20 @@ def run(run):
                     readback_rows=inc._size,
                 )
             with run.span("commit"):
-                nxt = lease_list(inc.snapshot(), out, k * period)
-        return out, nxt
+                snap = inc.snapshot()
+                nxt = lease_list(snap, out, k * period)
+                gone = preempted_list(snap, out)
+        return out, nxt, gone
 
     t0 = time.monotonic()
-    out, leases = one_round(0, [])
+    out, leases, preempted = one_round(0, [], [])
     first_round_s = time.monotonic() - t0
+    warmup_preempted = len(preempted)
     for k in range(1, 1 + int(traffic["warmup_rounds"])):
-        out, leases = one_round(k, leases)
+        out, leases, preempted = one_round(k, leases, preempted)
+        warmup_preempted += len(preempted)
+    if warmup_preempted:
+        run.report.update(residency_programs_warmed=warm_resident(resident))
     from armada_tpu.observe.xla import TELEMETRY
 
     comp0 = TELEMETRY.snapshot()
@@ -102,13 +116,15 @@ def run(run):
     # ---- the window ----------------------------------------------------
     k0 = 1 + int(traffic["warmup_rounds"])
     rounds = 0
+    window_preempted = 0
     traced = int(traffic["trace_rounds"])
     run.start_trace()
     t_start = time.perf_counter()
     deadline = t_start + run.seconds
     while True:
-        out, leases = one_round(k0 + rounds, leases)
+        out, leases, preempted = one_round(k0 + rounds, leases, preempted)
         rounds += 1
+        window_preempted += len(preempted)
         if run.recording and rounds >= traced:
             run.stop_trace()
         if time.perf_counter() >= deadline:
@@ -124,6 +140,7 @@ def run(run):
     print(
         "window "
         + json_line(rounds=rounds, seconds=t_end - t_start,
+                    preempted=window_preempted,
                     compiles=window_compiles["compiles"],
                     traces=window_compiles["traces"],
                     compiled_in_window=window_programs(run, misses0)),
@@ -149,8 +166,9 @@ def run(run):
 
 class Ledger:
     """The benchmark's own record of which jobs are queued and running,
-    kept from the leases it applies: the reference's input is rebuilt
-    from it, not taken from the program's incremental state."""
+    kept from the leases and preemptions it applies: the reference's
+    input is rebuilt from it, not taken from the program's incremental
+    state."""
 
     def __init__(self, running, queued):
         from armada_tpu.core.types import RunningJob
@@ -177,15 +195,19 @@ class Ledger:
             )
             self.where[jid] = len(self.ids)
             self.ids.append(jid)
-        for jid in done:
+        self.end(done)
+        for job in fresh:
+            self.queued[job.id] = job
+
+    def end(self, ids):
+        """Running jobs that end: completed, or preempted by a round."""
+        for jid in ids:
             del self.running[jid]
             i = self.where.pop(jid)
             last = self.ids.pop()
             if last != jid:
                 self.ids[i] = last
                 self.where[last] = i
-        for job in fresh:
-            self.queued[job.id] = job
 
     def rebuild(self, cfg, config):
         """The reference's round, built from this record alone."""
@@ -225,3 +247,10 @@ def lease_list(snap, out, ts):
         (str(snap.job_ids[j]), snap.node_ids[int(node[j])], int(prio[j]), ts)
         for j in sched
     ]
+
+
+def preempted_list(snap, out):
+    """The ids of the running jobs the round preempted."""
+    J = snap.num_jobs
+    return [str(snap.job_ids[j])
+            for j in np.flatnonzero(np.asarray(out["preempted_mask"])[:J])]

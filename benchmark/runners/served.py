@@ -5,12 +5,22 @@ with the deployment's fake executors, as the program ships it: its own
 cycle loop, solver ladder, admission firewall and event log. Everything
 runs in this one process, which alone holds the chip.
 
-Set-up creates the queues, submits the backlog over gRPC, submits a few
-arrival-shaped jobs so their scheduling keys exist, starts the plane and
-waits out its first rounds (the compile, or the persistent cache's
-load). The window is an open loop: arrivals are due at fixed Poisson
-gaps from the seed, each one a SubmitJobs call into the next queue of an
-even round; one watcher thread per queue follows the arrivals' jobset on
+Set-up creates the queues, submits the fill (where the deployment has
+one) and the backlog over gRPC, submits a few arrival-shaped jobs so
+their scheduling keys exist, starts the plane and waits out its first
+rounds (the compile, or the persistent cache's load). A deployment's
+optional `fill` block (`count` jobs in the exact shares of its `sizes`,
+of its `priority_class` where a size names none, each running
+`runtime_s`) starts the window on a full fleet: set-up then waits until
+the fill's leases, as the watchers see them, hold `until_fraction` of
+the fleet's CPU, and fails the run after `FILL_WAIT_S` seconds. The fill
+goes into each queue's arrivals jobset, so the watchers that time the
+arrivals see its leases too (the server admits a bounded number of
+watch streams).
+
+The window is an open loop: arrivals are due at fixed Poisson gaps from
+the seed, each one a SubmitJobs call into the next queue of an even
+round; one watcher thread per queue follows the arrivals' jobset on
 WatchJobSet. A job's lease latency is from when it was due to the moment
 its watcher sees its JobRunLeased event. After the window, arrivals are
 followed until leased, for up to `drain_s` (outside the window); one
@@ -42,6 +52,12 @@ from benchmark import check, generate
 from benchmark.harness import (
     T_START, check_window_compiles, log_setup, warm_resident, window_programs,
 )
+from benchmark.reference.inputs import quantity
+
+
+# Seconds set-up waits, after the first solved round, for the fill to
+# lease its share of the fleet.
+FILL_WAIT_S = 300.0
 
 
 def run(run):
@@ -84,15 +100,20 @@ def _drive(run, plane, stop):
     sched = plane.scheduler
     lo, hi = float(config["runtime_s"]["min"]), float(config["runtime_s"]["max"])
     gang_runtime = float((config["backlog"].get("gang") or {}).get("runtime_s", hi))
+    fill = config.get("fill")
+    fill_ids: set[str] = set()
     seed = run.seed
     client = connect(plane.address)
     record = Record(client)
 
     def runtime_for(job_id, seed=seed):
-        """From the seed per job; a gang's members end together."""
+        """From the seed per job; a gang's members end together, and the
+        fill runs its own runtime."""
         rec = record.jobs.get(job_id)
         if rec is not None and rec[5]:
             return gang_runtime
+        if job_id in fill_ids:
+            return float(fill["runtime_s"])
         u = zlib.crc32(f"{seed}:{job_id}".encode()) / 2**32
         return lo + (hi - lo) * u
 
@@ -104,10 +125,14 @@ def _drive(run, plane, stop):
     t0 = time.monotonic()
     for name, pf in zip(names, generate.priority_factors(config)):
         client.create_queue(name, priority_factor=pf)
+    arr = traffic["arrivals"]
+    if fill:
+        for queue, jobs in fill_jobs(config, seed):
+            for i in range(0, len(jobs), 2000):
+                fill_ids.update(record.submit(queue, arr["jobset"], jobs[i:i + 2000]))
     for queue, jobset, jobs in backlog(config, seed):
         for i in range(0, len(jobs), 2000):
             record.submit(queue, jobset, jobs[i:i + 2000])
-    arr = traffic["arrivals"]
     arrival = {
         "priority": int(arr["priority"]),
         "priority_class": arr["priority_class"],
@@ -124,6 +149,12 @@ def _drive(run, plane, stop):
     plane.start()
     first = wait_solved(plane, keeper, timeout=1100.0)
     first_round_s = time.monotonic() - t0
+    if fill:
+        t0 = time.monotonic()
+        fill_cpu = wait_filled(record, watchers, fill_ids, fleet_cpu(config),
+                               float(fill["until_fraction"]))
+        run.report.update(fill_s=round(time.monotonic() - t0, 3),
+                          fill_leased_cpu=fill_cpu)
     t0 = time.monotonic()
     warmed = warm_resident(sched._resident.get(config["pool"]))
     warm_s = time.monotonic() - t0
@@ -194,6 +225,7 @@ def _drive(run, plane, stop):
             "generator_late_p50_s": float(np.percentile(late, 50)) if len(late) else 0.0,
             "generator_late_max_s": float(late.max()) if len(late) else 0.0,
             "rounds_kept": len(keeper.rounds),
+            "preempted": sum(preemptions(snap, out)[0] for snap, out in keeper.rounds),
             "window_opened_s": window_opened_s,
             "round_shapes": keeper.shapes,
             "unleased": unleased,
@@ -217,6 +249,7 @@ def _drive(run, plane, stop):
     picks = sorted(rng.choice(len(rounds), size=k, replace=False)) if k else []
     cfg = generate.scheduling_config(config)
     divergences, gap, mismatches = 0, 0.0, 0
+    preempted, preempting = 0, 0
     for i in picks:
         snap, out = rounds[i]
         numbers = check.compare_to_reference(
@@ -225,6 +258,9 @@ def _drive(run, plane, stop):
         divergences += numbers["placement_divergences"]
         gap = max(gap, numbers["fair_share_gap"])
         mismatches += lease_mismatches(snap, out, seen)
+        n, onto = preemptions(snap, out)
+        preempted += n
+        preempting += onto
     limits = run.cell.limits
     run.check["placement_divergences"] = (divergences, limits["placement_divergences"])
     run.check["fair_share_gap"] = (gap, limits["fair_share_gap"])
@@ -233,6 +269,8 @@ def _drive(run, plane, stop):
     run.check["rounds_compared_short"] = (int(traffic["compare_rounds"]) - k, 0)
     run.check["failovers"] = (failovers(plane), 0)
     print("reference " + json.dumps({"rounds": [int(i) for i in picks],
+                                      "preempted": preempted,
+                                      "preempting_leases": preempting,
                                       "seconds": time.monotonic() - t0}),
           flush=True)
 
@@ -353,16 +391,71 @@ def backlog(config, seed):
                  "gang": {"id": f"gang-{card}", "cardinality": card}}
             ] * card
             count -= card
-        sizes = generate.shuffled_labels(
-            count, [s["share"] for s in spec["sizes"]], rng
-        )
-        kinds = [
-            {"priority": prio, "priority_class": s["priority_class"],
-             "requests": {"cpu": s["cpu"], "memory": s["memory"]}}
-            for s in spec["sizes"]
-        ]
-        jobs += [kinds[i] for i in sizes]
+        jobs += sized_jobs(count, spec["sizes"], prio, spec.get("priority_class"), rng)
         yield name, f"{name}-backlog", jobs
+
+
+def fill_jobs(config, seed):
+    """(queue, [job dicts]) per queue of the fill: its `count` jobs spread
+    evenly over the queues, at the backlog's job priority."""
+    spec = config["fill"]
+    names = generate.queue_names(config)
+    prio = int(config["backlog"]["priority"])
+    per_queue = generate.exact_counts(int(spec["count"]), [1.0] * len(names))
+    rng = generate.rng_for(seed, 17)
+    for q, name in enumerate(names):
+        yield name, sized_jobs(
+            int(per_queue[q]), spec["sizes"], prio, spec["priority_class"], rng
+        )
+
+
+def sized_jobs(count, sizes, prio, priority_class, rng):
+    """`count` job dicts in the exact shares of `sizes`, in an order drawn
+    from rng; a size without a priority class takes `priority_class`."""
+    labels = generate.shuffled_labels(count, [s["share"] for s in sizes], rng)
+    kinds = [
+        {"priority": prio, "priority_class": s.get("priority_class", priority_class),
+         "requests": {"cpu": s["cpu"], "memory": s["memory"]}}
+        for s in sizes
+    ]
+    return [kinds[i] for i in labels]
+
+
+def fleet_cpu(config) -> float:
+    ex = config["executors"]
+    return float(int(ex["count"]) * int(ex["nodes"]) * quantity(ex["cpu"]))
+
+
+def wait_filled(record, watchers, fill_ids, fleet, fraction) -> float:
+    """Wait until the fill's leases its watchers have seen hold `fraction`
+    of the fleet's `fleet` CPU; returns the CPU they hold. Fails the run
+    after FILL_WAIT_S seconds."""
+    deadline = time.monotonic() + FILL_WAIT_S
+    while True:
+        leased = leased_times(watchers)
+        cpu = float(sum(quantity(record.jobs[j][3]["cpu"])
+                          for j in fill_ids if j in leased))
+        if cpu >= fraction * fleet:
+            return cpu
+        if time.monotonic() >= deadline:
+            raise RuntimeError(
+                f"set-up: the fill leased {cpu:g} of the fleet's {fleet:g} CPU in "
+                f"{FILL_WAIT_S:g} s; its until_fraction asks for {fraction:g}"
+            )
+        time.sleep(0.2)
+
+
+def preemptions(snap, out) -> tuple[int, int]:
+    """The jobs a round preempted, and its leases onto a node on which it
+    preempted one."""
+    J = snap.num_jobs
+    pre = np.flatnonzero(np.asarray(out["preempted_mask"])[:J])
+    if not len(pre):
+        return 0, 0
+    nodes = np.unique(np.asarray(snap.job_node)[pre])
+    sched = np.flatnonzero(np.asarray(out["scheduled_mask"])[:J])
+    onto = np.isin(np.asarray(out["assigned_node"])[sched], nodes)
+    return len(pre), int(onto.sum())
 
 
 class RoundKeeper:

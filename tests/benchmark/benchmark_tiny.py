@@ -73,13 +73,53 @@ def write_tiny_root(root):
         {"name": "tiny.served", "config": "tiny-fleet", "traffic": "tiny-served",
          "chips": 1, "why": "test"},
     ]
+    # Cells of the real benchmark other than these two have no tiny
+    # counterpart: they drop out of every metric's list, and a metric
+    # left with no cell drops out of the tiny benchmark.
     rename = {"flagship.steady": "tiny.steady", "fleet.served": "tiny.served"}
-    for m in bench["end_to_end"] + bench["per_layer"]:
-        if "workloads" in m:
-            m["workloads"] = [rename[w] for w in m["workloads"]]
+    for group in ("end_to_end", "per_layer"):
+        kept = []
+        for m in bench[group]:
+            if "workloads" in m:
+                m["workloads"] = [rename[w] for w in m["workloads"] if w in rename]
+                if not m["workloads"]:
+                    continue
+            kept.append(m)
+        bench[group] = kept
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(bench, f)
     return str(root)
+
+
+def add_cell(root, cell, config, mix, reports):
+    """Add cell `cell` to a tiny root as a later change adds one, by files
+    and entries alone: the configuration's file (named by its "name"),
+    the mix's file where `mix` is a dict (else the name of a mix the root
+    has), an entry for each, and the cell's name appended to the
+    `workloads` of the end-to-end metrics it `reports`. Returns the mix's
+    name."""
+    bdir = os.path.join(root, "benchmark")
+    name = config["name"]
+    with open(os.path.join(bdir, "configs", f"{name}.json"), "w") as f:
+        json.dump(config, f)
+    if isinstance(mix, dict):
+        mix_name = f"{cell}-mix"
+        with open(os.path.join(bdir, "traffic", f"{mix_name}.json"), "w") as f:
+            json.dump(mix, f)
+    else:
+        mix_name = mix
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    bench["configs"].append({"name": name, "source": "test", "reduced": [],
+                             "why": "test", "file": f"benchmark/configs/{name}.json"})
+    bench["workloads"].append({"name": cell, "config": name, "traffic": mix_name,
+                               "chips": 1, "why": "test"})
+    for m in bench["end_to_end"]:
+        if m["name"] in reports:
+            m["workloads"].append(cell)
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(bench, f)
+    return mix_name
 
 
 # Faults planted under the timed path: the solve's answer, broken where

@@ -11,7 +11,7 @@ import sys
 
 import pytest
 
-from benchmark_tiny import REPO, write_tiny_root
+from benchmark_tiny import REPO, add_cell, write_tiny_root
 from benchmark.harness import Cell, Run, result_line
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -101,47 +101,75 @@ def test_benchmark_json_names_are_unique():
     assert len(pairs) == len(set(pairs))
 
 
-def test_benchmark_adds_a_cell_by_files_alone(tmp_path, monkeypatch):
+# A throwaway cell of each runner: the deployment it copies, its mix,
+# the end-to-end metrics it reports and a reader of its own.
+THROWAWAY = {
+    "round_loop": {
+        "config": "tiny-flagship", "e2e": ["cycle_s"], "moves": "cycle_s",
+        "mix": {"runner": "round_loop", "warmup_rounds": 1, "trace_rounds": 2},
+        "reader": "def read(run):\n    return len(run.spans.get('round', [])) or None\n",
+    },
+    "served": {
+        "config": "tiny-fleet", "e2e": ["lease_p50_s", "lease_p95_s"],
+        "moves": "lease_p95_s", "mix": "tiny-served",
+        "reader": ("def read(run):\n"
+                   "    return run.counters.get('scheduler_cycle_seconds_count') or None\n"),
+    },
+}
+
+
+@pytest.mark.parametrize("runner", sorted(THROWAWAY))
+def test_benchmark_adds_a_cell_by_files_alone(tmp_path, monkeypatch, runner):
     """A throwaway configuration, mix, cell and per-layer metric: new
-    files and new entries, and no file of the benchmark edited."""
+    files and new entries, and no file of the benchmark edited. The
+    real BENCHMARK.json already holds a cell that has no tiny
+    counterpart, as it does once a later change adds one."""
+    import benchmark_tiny
     from armada_tpu.core import resources
 
     monkeypatch.setattr(resources, "ensure_native", lambda *a, **k: False)
+    spec = THROWAWAY[runner]
+    real = json.loads(json.dumps(BENCH))
+    real["workloads"].append({"name": "later.cell", "config": real["configs"][0]["name"],
+                              "traffic": "later-mix", "chips": 1, "why": "test"})
+    for m in real["end_to_end"]:
+        if m["name"] in spec["e2e"]:
+            m["workloads"].append("later.cell")
+    real["per_layer"].append({"name": "later_ms.later", "unit": "ms", "better": "lower",
+                              "source": "host_clock", "layer": "harness",
+                              "moves": spec["moves"], "workloads": ["later.cell"]})
+    load = benchmark_tiny._load
+    monkeypatch.setattr(benchmark_tiny, "_load",
+                        lambda rel: real if rel == "BENCHMARK.json" else load(rel))
     root = write_tiny_root(tmp_path)
-    bdir = os.path.join(root, "benchmark")
-    with open(os.path.join(bdir, "configs", "tiny-flagship.json")) as f:
-        cfg = json.load(f)
-    cfg.update(name="throwaway")
-    cfg["backlog"]["count"] = 1000
-    with open(os.path.join(bdir, "configs", "throwaway.json"), "w") as f:
-        json.dump(cfg, f)
-    with open(os.path.join(bdir, "traffic", "throwaway-mix.json"), "w") as f:
-        json.dump({"runner": "round_loop",
-                   "warmup_rounds": 1, "trace_rounds": 2}, f)
-    with open(os.path.join(bdir, "metrics", "rounds_seen.round.py"), "w") as f:
-        f.write("def read(run):\n    return len(run.spans.get('round', [])) or None\n")
     with open(os.path.join(root, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    bench["configs"].append({"name": "throwaway", "source": "test", "reduced": [],
-                             "why": "test", "file": "benchmark/configs/throwaway.json"})
-    bench["workloads"].append({"name": "throwaway.cell", "config": "throwaway",
-                               "traffic": "throwaway-mix", "chips": 1, "why": "test"})
-    for m in bench["end_to_end"]:
-        if m["name"] == "cycle_s":
-            m["workloads"].append("throwaway.cell")
-    bench["per_layer"].append({"name": "rounds_seen.round", "unit": "rounds",
+    assert [w["name"] for w in bench["workloads"]] == ["tiny.steady", "tiny.served"]
+    assert "later_ms.later" not in {m["name"] for m in bench["per_layer"]}
+
+    bdir = os.path.join(root, "benchmark")
+    with open(os.path.join(bdir, "configs", spec["config"] + ".json")) as f:
+        cfg = json.load(f)
+    cfg.update(name="throwaway")
+    add_cell(root, "throwaway.cell", cfg, spec["mix"], spec["e2e"])
+    with open(os.path.join(bdir, "metrics", "throwaway_seen.cell.py"), "w") as f:
+        f.write(spec["reader"])
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    bench["per_layer"].append({"name": "throwaway_seen.cell", "unit": "count",
                                "better": "higher", "source": "host_clock",
-                               "layer": "harness", "moves": "cycle_s",
+                               "layer": "harness", "moves": spec["moves"],
                                "workloads": ["throwaway.cell"]})
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(bench, f)
     from benchmark.harness import run_cell
 
-    out = run_cell(root, "throwaway.cell", 9, 0.5, True,
+    out = run_cell(root, "throwaway.cell", 9, 2.0 if runner == "served" else 0.5, True,
                    require_accelerator=False, out_dir=str(tmp_path / "out"),
                    log=lambda m: None)
-    assert out["correct"]
-    assert out["metrics"]["rounds_seen.round"]["value"] >= 1
+    assert out["correct"], out["check"]
+    assert out["metrics"]["throwaway_seen.cell"]["value"] >= 1
+    assert out["metrics"]["throwaway_seen.cell"]["unit"] == "count"
 
 
 class _Dev:
@@ -217,3 +245,29 @@ def test_benchmark_run_needs_the_program(tmp_path):
     proc = _run_py(tmp_path, {"JAX_PLATFORMS": "cpu"})
     assert proc.returncode != 0
     assert proc.stdout.strip() == ""
+
+
+def test_benchmark_tiny_root_is_the_same_with_a_cell_it_does_not_know(tmp_path, monkeypatch):
+    """The tiny root's BENCHMARK.json comes out the same whether or not
+    the real one holds a cell the tiny root has no copy of."""
+    import benchmark_tiny
+
+    with open(os.path.join(write_tiny_root(tmp_path / "a"), "BENCHMARK.json")) as f:
+        plain = json.load(f)
+    real = json.loads(json.dumps(BENCH))
+    real["workloads"].append({"name": "later.cell", "config": real["configs"][0]["name"],
+                              "traffic": "later-mix", "chips": 1, "why": "test"})
+    for m in real["end_to_end"]:
+        if "workloads" in m:
+            m["workloads"].append("later.cell")
+    real["end_to_end"].append({"name": "later_s", "unit": "s", "better": "lower",
+                               "bound": 0.1, "source": "host_clock",
+                               "workloads": ["later.cell"]})
+    real["per_layer"].append({"name": "later_ms.later", "unit": "ms", "better": "lower",
+                              "source": "host_clock", "layer": "harness",
+                              "moves": "later_s", "workloads": ["later.cell"]})
+    load = benchmark_tiny._load
+    monkeypatch.setattr(benchmark_tiny, "_load",
+                        lambda rel: real if rel == "BENCHMARK.json" else load(rel))
+    with open(os.path.join(write_tiny_root(tmp_path / "b"), "BENCHMARK.json")) as f:
+        assert json.load(f) == plain

@@ -5,7 +5,9 @@ sync fits inside the outside one, and a program without the span API or
 the counter gives none of them (as the benchmark runs over an older
 program)."""
 
+import json
 import math
+import os
 
 import pytest
 
@@ -50,16 +52,24 @@ class _OldTracer:
     finished: list = []
 
 
-@pytest.mark.parametrize("cell", ["flagship.steady", "fleet.served"])
+with open(os.path.join(REPO, "BENCHMARK.json")) as _f:
+    CELLS = [w["name"] for w in json.load(_f)["workloads"]]
+
+
+@pytest.mark.parametrize("cell", CELLS)
 def test_benchmark_program_readers_silent_without_the_api(tmp_path, monkeypatch, cell):
+    """Every reader of the program's spans and counters gives nothing
+    where the program has neither."""
     from armada_tpu.utils import tracing
 
     monkeypatch.setattr(tracing, "TRACER", _OldTracer())
     monkeypatch.delattr(tracing, "GC_PAUSES")
     run = Run(Cell(REPO, cell), 1, 51.0, True, str(tmp_path))
     run.devices = [_Dev()]
-    run.e2e.update(setup_s=1.0, cycle_s=5.0)
+    run.e2e.update({m["name"]: 1.0 for m in run.cell.end_to_end})
     run.attempted = 10
     run.check.update(placement_divergences=(0, 0))
     out = result_line(run, lambda m: None)
-    assert not set(ROUND + SERVED) & set(out["metrics"])
+    program = {m["name"] for m in run.cell.per_layer
+               if m["source"] in ("program_span", "program_counter")}
+    assert not program & set(out["metrics"])

@@ -13,6 +13,9 @@ from benchmark_tiny import (
     FAULTS, REPO, plant_fault, plant_window_compile, write_tiny_root,
 )
 
+with open(os.path.join(REPO, "BENCHMARK.json")) as _f:
+    BENCH = json.load(_f)
+
 
 def test_benchmark_round_loop_is_correct(tiny_run):
     out, lines = tiny_run("tiny.steady", seed=2**33 + 5)
@@ -28,8 +31,12 @@ def test_benchmark_round_loop_is_correct(tiny_run):
 def test_benchmark_round_loop_traced_reads_its_layers(tiny_run):
     out, _ = tiny_run("tiny.steady", trace=True)
     assert out["correct"]
-    assert {"delta_ms.round", "sync_ms.round", "solve_ms.round"} <= set(out["metrics"])
-    assert all(m["unit"] == "ms" for m in out["metrics"].values())
+    assert {"delta_ms.round", "sync_ms.round", "solve_ms.round",
+            "solve_syncs.round"} <= set(out["metrics"])
+    units = {m["name"]: m["unit"] for m in BENCH["per_layer"]}
+    for name, m in out["metrics"].items():
+        assert m["unit"] == units[name], name
+    assert out["metrics"]["solve_syncs.round"]["value"] >= 1
 
 
 @pytest.mark.parametrize("fault", sorted(FAULTS))
@@ -110,3 +117,27 @@ def test_benchmark_round_loop_float32_control_is_not_correct(tmp_path):
     gap = out["check"]["fair_share_gap"]
     assert gap["value"] > gap["limit"]
     assert not out["correct"]
+
+
+def test_benchmark_round_loop_follows_preemption(tiny_root, tiny_run, capsys):
+    """A full fleet of small preemptible jobs and a backlog with large
+    urgent ones: rounds preempt, the runner ends the preempted jobs as
+    the scheduler service does, and the reference, rebuilt from the
+    benchmark's own record, agrees with the last round."""
+    from benchmark_tiny import add_cell, tiny_configs
+
+    config = tiny_configs()["tiny-flagship"]
+    config["name"] = "tiny-preempt"
+    config["running"].update(count=2048, priority_class="low", cpu="1", memory="2Gi")
+    config["backlog"]["sizes"] = [
+        {"share": 1, "priority_class": "high", "cpu": "4", "memory": "8Gi"},
+        {"share": 1, "cpu": "1", "memory": "2Gi"},
+    ]
+    mix = {"runner": "round_loop", "warmup_rounds": 4, "trace_rounds": 2}
+    add_cell(tiny_root, "tiny.preempt", config, mix, ["cycle_s"])
+    out, _ = tiny_run("tiny.preempt", seed=2**32 + 11, seconds=2.0)
+    window = next(json.loads(line[len("window "):])
+                  for line in capsys.readouterr().out.splitlines()
+                  if line.startswith("window "))
+    assert window["preempted"] > 0
+    assert out["correct"], out["check"]
